@@ -1,0 +1,163 @@
+"""The cell's store: its cluster description, the objects a GET mix
+reads, and the warm-up of every program the mix dispatches.
+
+Destinations are directories under ``<checkout>/.bench_work/store`` on
+the machine's disk, one per shard slot, with a JSON metadata directory
+beside them; ``run.py`` removes the whole tree when the run ends.
+"""
+
+from __future__ import annotations
+
+import asyncio
+import json
+import os
+
+from payload import Payload
+
+
+def cluster_obj(work: str, config: dict, backend: str) -> dict:
+    root = os.path.join(work, "store")
+    disks = [os.path.join(root, f"disk{i:02d}")
+             for i in range(config["destinations"])]
+    meta = os.path.join(root, "meta")
+    for d in disks + [meta]:
+        os.makedirs(d, exist_ok=True)
+    return {"destinations": [{"location": d} for d in disks],
+            "metadata": {"type": "path", "format": "json", "path": meta},
+            "tunables": {"backend": backend},
+            "profiles": {"default": dict(config["profile"])}}
+
+
+def meta_path(work: str, name: str) -> str:
+    return os.path.join(work, "store", "meta", name)
+
+
+#: payload indices of stored objects start here, apart from PUT bodies
+STORE_BASE = 1 << 30
+
+
+def store_name(index: int) -> str:
+    return f"s/{index}"
+
+
+def store_index(name: str) -> int:
+    return STORE_BASE + int(name.rsplit("/", 1)[1])
+
+
+def max_size(traffic: dict, key: str) -> int:
+    return max(_sizes(traffic, key))
+
+
+async def build(work: str, config: dict, traffic: dict, seed: int
+                ) -> list[str]:
+    """Write the mix's ``store_objects`` objects with the mix's
+    ``store_backend``, then delete the chunk files of its ``lost_slots``
+    in every part.  Returns the objects' names."""
+    from chunky_bits_tpu.cluster import Cluster
+    from chunky_bits_tpu.utils.aio import BytesReader
+
+    size = int(traffic["store_object_bytes"])
+    count = int(traffic["store_objects"])
+    payload = Payload(seed, size)
+    cluster = Cluster.from_obj(cluster_obj(
+        work, config, traffic.get("store_backend", config["backend"])))
+    profile = cluster.get_profile(None)
+    gate = asyncio.Semaphore(16)
+
+    async def put(i: int) -> None:
+        async with gate:
+            body = payload.body(STORE_BASE + i, size).tobytes()
+            await cluster.write_file(store_name(i), BytesReader(body),
+                                     profile)
+
+    await asyncio.gather(*(put(i) for i in range(count)))
+    lost = traffic.get("lost_slots", [])
+    for i in range(count):
+        with open(meta_path(work, store_name(i))) as f:
+            ref = json.load(f)
+        for part in ref["parts"]:
+            chunks = part["data"] + part["parity"]
+            for slot in lost:
+                for loc in chunks[slot]["locations"]:
+                    os.remove(loc)
+    return [store_name(i) for i in range(count)]
+
+
+def bytes_on_disk(work: str) -> int:
+    total = 0
+    for dirpath, _dirs, files in os.walk(os.path.join(work, "store")):
+        for f in files:
+            total += os.path.getsize(os.path.join(dirpath, f))
+    return total
+
+
+def shard_lengths(config: dict, sizes) -> dict[int, int]:
+    """Shard length -> the most parts of that length one object of these
+    sizes has."""
+    d = config["profile"]["data"]
+    chunk = 1 << config["profile"]["chunk_size"]
+    out: dict[int, int] = {}
+    for size in sizes:
+        if size >= d * chunk:
+            out[chunk] = max(out.get(chunk, 0), size // (d * chunk))
+        tail = size % (d * chunk)
+        if tail:
+            s = -(-tail // d)
+            out[s] = max(out.get(s, 0), 1)
+    return out
+
+
+def _sizes(traffic: dict, key: str) -> list[int]:
+    v = traffic[key]
+    return [s for s, _ in v] if isinstance(v, list) else [v]
+
+
+def ops_of(traffic: dict) -> set[str]:
+    v = traffic["op"]
+    return {o for o, _ in v} if isinstance(v, list) else {v}
+
+
+def warm(cluster, config: dict, traffic: dict) -> int:
+    """Run every codec program the mix can dispatch once: encode for PUTs,
+    the decode of the lost slots for GETs, for each shard length and each
+    batch size from 1 to the mix's ``warm_batches``, or to the parts of
+    that length all clients can have in flight if that is fewer.
+    Returns the calls made."""
+    import numpy as np
+
+    from chunky_bits_tpu.ops.backend import get_coder
+
+    prof = config["profile"]
+    d, p = prof["data"], prof["parity"]
+    coder = get_coder(d, p, config["backend"])
+    top = int(traffic.get("warm_batches", 1))
+    clients = int(traffic["clients"])
+    calls = []
+    ops = ops_of(traffic)
+    if "put" in ops:
+        for s, n in shard_lengths(
+                config, _sizes(traffic, "object_bytes")).items():
+            calls.append((s, min(top, n * clients), coder.encode_batch))
+    lost = sorted(traffic.get("lost_slots", []))
+    if "get" in ops and lost:
+        present = [i for i in range(d + p) if i not in lost]
+        wanted = [i for i in lost if i < d]
+        for s, n in shard_lengths(
+                config, _sizes(traffic, "store_object_bytes")).items():
+            calls.append((s, min(top, n * clients),
+                          lambda x: coder.reconstruct_batch_picked(
+                              x, present, wanted)))
+    # the single-chip backend takes its input from an uncommitted
+    # jax.device_put, and jit caches committed and uncommitted inputs
+    # apart: warm with uncommitted zeros made on the device (no copy in)
+    on_device = config["backend"] == "jax"
+    if on_device:
+        import jax.numpy as jnp
+    n = 0
+    for s, most, call in calls:
+        host = None if on_device else np.zeros((most, d, s), np.uint8)
+        for b in range(1, most + 1):
+            call(jnp.zeros((b, d, s), jnp.uint8) if on_device
+                 else host[:b])
+            n += 1
+    return n
