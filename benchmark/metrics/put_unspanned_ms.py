@@ -1,0 +1,8 @@
+"""Mean ms of a PUT's ``gateway.put`` span that none of its child spans
+covers: what the spans do not explain (``benchmark/program_spans.py``)."""
+
+import program_spans
+
+
+def read(w):
+    return program_spans.unspanned_ms(__file__, "put")

@@ -35,6 +35,7 @@ from chunky_bits_tpu.file.location import Location
 from chunky_bits_tpu.file.profiler import ProfileReport, new_profiler
 from chunky_bits_tpu.file.reader import FileReadBuilder
 from chunky_bits_tpu.file.writer import FileWriteBuilder
+from chunky_bits_tpu.obs import tracing as obs_tracing
 from chunky_bits_tpu.utils import aio
 
 
@@ -227,7 +228,8 @@ class Cluster:
         self._file_ref_gen += 1
         self._file_refs.pop(path, None)
         try:
-            await self.metadata.write(path, file_ref.to_obj())
+            with obs_tracing.span("meta.write"):
+                await self.metadata.write(path, file_ref.to_obj())
         finally:
             self._file_ref_gen += 1
             self._file_refs.pop(path, None)
@@ -299,7 +301,8 @@ class Cluster:
                 self._file_refs.move_to_end(path)
                 return ref
         gen = self._file_ref_gen
-        obj = await self.metadata.read(path)
+        with obs_tracing.span("meta.read"):
+            obj = await self.metadata.read(path)
         ref = FileReference.from_obj(obj)
         # insert only if no write invalidated the cache while this read
         # was in flight — otherwise we could durably cache a stale ref

@@ -542,14 +542,13 @@ class FilePart:
             failures: list[tuple[Location, str]] = []
             if health is not None:
                 health.note_primary()  # hedge-budget accrual
-            t0 = _clock.monotonic()
-            if hedging and len(chunk.locations) > 1:
-                data = await fetch_hedged(chunk, failures)
-            else:
-                data = await fetch_serial(chunk, failures)
-            obs_tracing.record_span(
-                "chunk_fetch", "network", t0, _clock.monotonic() - t0,
-                "ok" if data is not None else "miss")
+            with obs_tracing.span("reader.fetch") as fetch:
+                if hedging and len(chunk.locations) > 1:
+                    data = await fetch_hedged(chunk, failures)
+                else:
+                    data = await fetch_serial(chunk, failures)
+                fetch.end("ok" if data is not None else "miss",
+                          bytes=0 if data is None else _buf_len(data))
             if failures and cx.profiler is not None:
                 for location, err in failures:
                     cx.profiler.log_location_failure(location, err)
@@ -653,12 +652,11 @@ class FilePart:
                 np.frombuffer(s, dtype=np.uint8) if s is not None else None
                 for s in slots
             ]
-            t0 = _clock.monotonic()
-            arrays = await _reconstruct(arrays, d, p, coder, backend,
-                                        batcher, data_only=True,
-                                        code=self.code)
-            obs_tracing.record_span("reconstruct", "compute", t0,
-                                    _clock.monotonic() - t0)
+            with obs_tracing.span("reader.reconstruct",
+                                  rows=len(rebuilt_idx)):
+                arrays = await _reconstruct(arrays, d, p, coder, backend,
+                                            batcher, data_only=True,
+                                            code=self.code)
             # rebuilt rows stay as buffers (memoryview over the array) —
             # every consumer downstream (join, hashing, socket/stdout
             # writes) takes buffer objects, so no tobytes copy

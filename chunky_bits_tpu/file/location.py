@@ -42,6 +42,8 @@ from chunky_bits_tpu.errors import (
 )
 from chunky_bits_tpu.file.hashing import AnyHash
 from chunky_bits_tpu.file.profiler import Profiler
+from chunky_bits_tpu.obs import metrics as obs_metrics
+from chunky_bits_tpu.obs import tracing as obs_tracing
 from chunky_bits_tpu.utils import aio
 
 OVERWRITE = "overwrite"
@@ -372,16 +374,28 @@ class _HttpBodyReader:
             self._resp = None
 
 
-class _ProfiledReader:
-    """Counts streamed bytes and emits exactly one profiler entry when
+def _io_done(location: "Location", cx: "LocationContext", op: str,
+             ok: bool, err: Optional[str], nbytes: int,
+             start: float) -> None:
+    """One location read or write completed: the ``cb_io_*`` series
+    always, the context's profiler log when one is attached."""
+    obs_metrics.record_io(op, ok, nbytes, _clock.monotonic() - start)
+    if cx.profiler is not None:
+        log = cx.profiler.log_read if op == "read" \
+            else cx.profiler.log_write
+        log(ok, err, location, nbytes, start)
+
+
+class _CountedReader:
+    """Counts streamed bytes and records exactly one I/O completion when
     the stream ends — EOF, error, or early close (the reference's
     streaming paths are unprofiled, ``// TODO: Profiler``
     src/file/location.rs:119,255)."""
 
-    def __init__(self, base, profiler: Profiler, location: "Location",
+    def __init__(self, base, cx: "LocationContext", location: "Location",
                  start: float):
         self._base = base
-        self._profiler = profiler
+        self._cx = cx
         self._location = location
         self._start = start
         self._total = 0
@@ -390,8 +404,8 @@ class _ProfiledReader:
     def _log(self, ok: bool, err: Optional[str] = None) -> None:
         if not self._logged:
             self._logged = True
-            self._profiler.log_read(ok, err, self._location, self._total,
-                                    self._start)
+            _io_done(self._location, self._cx, "read", ok, err,
+                     self._total, self._start)
 
     async def read(self, n: int = -1) -> bytes:
         try:
@@ -617,7 +631,7 @@ class Location:
     async def reader(self, cx: Optional[LocationContext] = None
                      ) -> aio.AsyncByteReader:
         """Open a streaming reader honoring the range
-        (src/file/location.rs:115-183).  Profiler-hooked: one entry per
+        (src/file/location.rs:115-183).  I/O-hooked: one completion per
         stream at EOF/close/error — the streaming-path hook the reference
         leaves as TODO (src/file/location.rs:119)."""
         cx = cx or default_context()
@@ -629,16 +643,17 @@ class Location:
             # error), one profiler entry
             if cx.health is not None:
                 cx.health.record(self, False, _clock.monotonic() - start)
-            if cx.profiler is not None:
-                cx.profiler.log_read(False, str(err), self, 0, start)
+            _io_done(self, cx, "read", False, str(err), 0, start)
             raise
         if cx.health is not None:
             # the scoreboard times the open (time-to-first-byte proxy);
             # stream duration depends on the consumer, not the node
             cx.health.record(self, True, _clock.monotonic() - start)
         if cx.profiler is None:
+            # the bare reader keeps its zero-copy surfaces (readinto,
+            # view_parts); chunk reads go through read()/read_view()
             return base
-        return _ProfiledReader(base, cx.profiler, self, start)
+        return _CountedReader(base, cx, self, start)
 
     async def _open_reader(self, cx: LocationContext
                            ) -> aio.AsyncByteReader:
@@ -758,15 +773,15 @@ class Location:
         return aio.TakeReader(base, rng.length)
 
     async def read(self, cx: Optional[LocationContext] = None) -> bytes:
-        """Read the full (ranged) content; profiler-hooked
+        """Read the full (ranged) content; I/O-hooked
         (src/file/location.rs:95-113)."""
         cx = cx or default_context()
         start = _clock.monotonic()
         if cx.health is not None:
             cx.health.begin(self)
         try:
-            # _open_reader, not reader(): this whole-buffer op logs its own
-            # single profiler entry below.
+            # _open_reader, not reader(): this whole-buffer op records its
+            # own single I/O completion below.
             reader = await self._open_reader(cx)
             try:
                 chunks = []
@@ -784,8 +799,7 @@ class Location:
         except LocationError as err:
             if cx.health is not None:
                 cx.health.finish(self, False, _clock.monotonic() - start)
-            if cx.profiler is not None:
-                cx.profiler.log_read(False, str(err), self, 0, start)
+            _io_done(self, cx, "read", False, str(err), 0, start)
             raise
         except BaseException:
             # cancellation (a hedge loser) or a non-Location failure:
@@ -796,8 +810,7 @@ class Location:
             raise
         if cx.health is not None:
             cx.health.finish(self, True, _clock.monotonic() - start)
-        if cx.profiler is not None:
-            cx.profiler.log_read(True, None, self, len(out), start)
+        _io_done(self, cx, "read", True, None, len(out), start)
         return out
 
     async def read_view(self, cx: Optional[LocationContext] = None
@@ -848,9 +861,12 @@ class Location:
                 t0 = _clock.monotonic()
                 view = store.map_view(root_name[1], rng.start or 0,
                                       rng.length)
-                if view is not None and health is not None:
-                    health.record(location, True,
-                                  _clock.monotonic() - t0)
+                if view is not None:
+                    if health is not None:
+                        health.record(location, True,
+                                      _clock.monotonic() - t0)
+                    obs_metrics.record_io("read", True, len(view),
+                                          _clock.monotonic() - t0)
                 return view
 
             return _map_slab
@@ -881,6 +897,8 @@ class Location:
                 # a node failure — the generic read re-records it; only
                 # a served view is a health sample
                 health.record(self, True, _clock.monotonic() - t0)
+            obs_metrics.record_io("read", True, end - start,
+                                  _clock.monotonic() - t0)
             return memoryview(mm)[start:end]
 
         return _map
@@ -889,78 +907,74 @@ class Location:
 
     async def write(self, data: bytes,
                     cx: Optional[LocationContext] = None) -> None:
-        """Whole-buffer write with conflict policy; profiler-hooked
-        (src/file/location.rs:185-244)."""
+        """Whole-buffer write with conflict policy; I/O-hooked and
+        spanned as ``dest.write`` (src/file/location.rs:185-244)."""
         cx = cx or default_context()
         if self.range.is_specified():
             raise WriteToRangeError()
-        start = _clock.monotonic()
-        if cx.health is not None:
-            cx.health.begin(self)
-        try:
-            if cx.on_conflict == IGNORE and await self.file_exists(cx):
+        with obs_tracing.span("dest.write", bytes=len(data)):
+            start = _clock.monotonic()
+            if cx.health is not None:
+                cx.health.begin(self)
+            try:
+                if cx.on_conflict == IGNORE and await self.file_exists(cx):
+                    if cx.health is not None:
+                        cx.health.finish(self, True,
+                                         _clock.monotonic() - start)
+                    _io_done(self, cx, "write", True, None, len(data), start)
+                    return
+                if self.is_slab():
+                    # packed publication: slab append + journal commit
+                    # (file/slab.py's atomic-index protocol) — the slab
+                    # plane's equivalent of the rename publication below
+                    root, name = self._slab_parts()
+                    store = self._slab_store()
+                    try:
+                        await asyncio.to_thread(store.append, name, data)
+                    except OSError as err:
+                        raise LocationError(str(err)) from err
+                elif self.is_sim():
+                    node, name = self._sim_node()
+                    await node.write(name, data)
+                elif self.is_local():
+                    try:
+                        await _atomic_publish(self.target, data)
+                    except OSError as err:
+                        raise LocationError(str(err)) from err
+                else:
+                    self._check_scheme(cx)
+                    sess = cx.http_session()
+                    try:
+                        resp = await sess.put(self.target, data=data,
+                                              **self._redirect_kwargs(cx))
+                        resp.release()
+                    except Exception as err:
+                        raise LocationError(f"http put failed: {err}") from err
+                    self._check_redirect(cx, resp)
+                    if resp.status >= 400:
+                        raise HttpStatusError(resp.status, self.target)
+            except LocationError as err:
                 if cx.health is not None:
-                    cx.health.finish(self, True,
-                                     _clock.monotonic() - start)
-                if cx.profiler is not None:
-                    cx.profiler.log_write(True, None, self, len(data), start)
-                return
-            if self.is_slab():
-                # packed publication: slab append + journal commit
-                # (file/slab.py's atomic-index protocol) — the slab
-                # plane's equivalent of the rename publication below
-                root, name = self._slab_parts()
-                store = self._slab_store()
-                try:
-                    await asyncio.to_thread(store.append, name, data)
-                except OSError as err:
-                    raise LocationError(str(err)) from err
-            elif self.is_sim():
-                node, name = self._sim_node()
-                await node.write(name, data)
-            elif self.is_local():
-                try:
-                    await _atomic_publish(self.target, data)
-                except OSError as err:
-                    raise LocationError(str(err)) from err
-            else:
-                self._check_scheme(cx)
-                sess = cx.http_session()
-                try:
-                    resp = await sess.put(self.target, data=data,
-                                          **self._redirect_kwargs(cx))
-                    resp.release()
-                except Exception as err:
-                    raise LocationError(f"http put failed: {err}") from err
-                self._check_redirect(cx, resp)
-                if resp.status >= 400:
-                    raise HttpStatusError(resp.status, self.target)
-        except LocationError as err:
+                    cx.health.finish(self, False, _clock.monotonic() - start)
+                _io_done(self, cx, "write", False, str(err), len(data), start)
+                raise
+            except BaseException:
+                if cx.health is not None:
+                    cx.health.finish(self, None, None)  # cancelled: no verdict
+                raise
             if cx.health is not None:
-                cx.health.finish(self, False, _clock.monotonic() - start)
-            if cx.profiler is not None:
-                cx.profiler.log_write(False, str(err), self, len(data), start)
-            raise
-        except BaseException:
-            if cx.health is not None:
-                cx.health.finish(self, None, None)  # cancelled: no verdict
-            raise
-        if cx.health is not None:
-            cx.health.finish(self, True, _clock.monotonic() - start)
-        if cx.profiler is not None:
-            cx.profiler.log_write(True, None, self, len(data), start)
+                cx.health.finish(self, True, _clock.monotonic() - start)
+            _io_done(self, cx, "write", True, None, len(data), start)
 
     async def write_from_reader(self, reader: aio.AsyncByteReader,
                                 cx: Optional[LocationContext] = None) -> int:
         """Streaming write; 1 MiB chunks into a chunked HTTP PUT or a local
         file (src/file/location.rs:246-309).  Returns bytes written.
-        Profiler-hooked (the reference's TODO at location.rs:255)."""
+        I/O-hooked (the reference's TODO at location.rs:255)."""
         cx = cx or default_context()
-        if cx.profiler is None and cx.health is None:
-            return await self._write_from_reader_impl(reader, cx)
         start = _clock.monotonic()
         # Count consumed bytes on the reader side so a stream that fails
-        # mid-body still profiles its partial progress.
+        # mid-body still records its partial progress.
         counted = aio.CountingReader(reader)
         if cx.health is not None:
             cx.health.begin(self)
@@ -969,9 +983,8 @@ class Location:
         except LocationError as err:
             if cx.health is not None:
                 cx.health.finish(self, False, _clock.monotonic() - start)
-            if cx.profiler is not None:
-                cx.profiler.log_write(False, str(err), self,
-                                      counted.total, start)
+            _io_done(self, cx, "write", False, str(err), counted.total,
+                     start)
             raise
         except BaseException:
             if cx.health is not None:
@@ -979,8 +992,7 @@ class Location:
             raise
         if cx.health is not None:
             cx.health.finish(self, True, _clock.monotonic() - start)
-        if cx.profiler is not None:
-            cx.profiler.log_write(True, None, self, total, start)
+        _io_done(self, cx, "write", True, None, total, start)
         return total
 
     async def _write_from_reader_impl(self, reader: aio.AsyncByteReader,

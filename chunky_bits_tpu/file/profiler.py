@@ -18,13 +18,14 @@ Two extensions beyond the reference:
   drops COUNTED — surfaced in the report (``Dropped<...>``) and the
   metrics registry (``cb_profiler_dropped_total``) so a saturated ring
   is an observable fact, not silent data loss.
-* **Registry feed.**  Every ``log_request``/``log_read``/``log_write``
-  also records into the process metrics registry
-  (``obs/metrics.py``: latency histograms + byte counters) and, when a
-  trace is active, a span onto the current request's trace — the
-  Profiler stays the one choke point all three telemetry surfaces
-  (stanza strings, /metrics series, /debug/traces spans) derive from,
-  so they can never disagree.
+* **Registry feed.**  Every ``log_request`` also records into the
+  process metrics registry (``obs/metrics.py``: latency histograms +
+  byte counters), so the access log's stanza strings and the /metrics
+  request series can never disagree.  Location I/O is recorded at the
+  location itself (``file/location.py``: ``cb_io_*`` for every read and
+  write, a ``dest.write`` span per chunk write), whether or not a
+  Profiler rides along, so ``log_read``/``log_write`` keep the report's
+  entries only.
 """
 
 from __future__ import annotations
@@ -35,7 +36,6 @@ from dataclasses import dataclass
 from typing import Optional
 
 from chunky_bits_tpu.obs import metrics as obs_metrics
-from chunky_bits_tpu.obs import tracing as obs_tracing
 
 #: the clock seam (canonical surface cluster/clock.py; utils-side
 #: import for cycle hygiene): the ``start_time`` values callers pass
@@ -358,11 +358,6 @@ class Profiler:
             dropped = self._append(self._entries, "entries", entry)
         if dropped:
             obs_metrics.record_dropped("entries")
-        obs_metrics.record_io("read", ok, length, end - start_time)
-        # no io.read span: the read path's network time is already
-        # attributed by the enclosing chunk_fetch span
-        # (file/file_part.py) — a second span here would double-count
-        # plane_ms["network"] in /debug/traces
 
     def log_write(self, ok: bool, error: Optional[str], location,
                   length: int, start_time: float) -> None:
@@ -373,10 +368,6 @@ class Profiler:
             dropped = self._append(self._entries, "entries", entry)
         if dropped:
             obs_metrics.record_dropped("entries")
-        obs_metrics.record_io("write", ok, length, end - start_time)
-        obs_tracing.record_span("io.write", "network", start_time,
-                                end - start_time,
-                                "ok" if ok else "error")
 
     def drain(self) -> list[ResultLog]:
         with self._lock:

@@ -49,6 +49,7 @@ import numpy as np
 from chunky_bits_tpu.errors import FileWriteError
 from chunky_bits_tpu.file.file_part import FilePart
 from chunky_bits_tpu.file.file_reference import FileReference
+from chunky_bits_tpu.obs import tracing as obs_tracing
 from chunky_bits_tpu.ops import get_coder
 from chunky_bits_tpu.utils import aio
 
@@ -263,13 +264,16 @@ class FileWriteBuilder:
                     for i in indices:
                         results[i] = ([], [], 0, None)
                     return
-                if encode_batcher is not None:
-                    parity_batch, digest_batch = \
-                        await encode_batcher.encode_hash(
-                            d, p, stacked, code=coder.code)
-                else:
-                    parity_batch, digest_batch = \
-                        await pipeline.encode_hash(coder, stacked)
+                with obs_tracing.span("writer.encode",
+                                      parts=stacked.shape[0],
+                                      bytes=stacked.nbytes):
+                    if encode_batcher is not None:
+                        parity_batch, digest_batch = \
+                            await encode_batcher.encode_hash(
+                                d, p, stacked, code=coder.code)
+                    else:
+                        parity_batch, digest_batch = \
+                            await pipeline.encode_hash(coder, stacked)
                 for bi, i in enumerate(indices):
                     results[i] = (
                         list(stacked[bi]),
@@ -362,7 +366,9 @@ class FileWriteBuilder:
                 # lint: lock-discipline-ok permit transferred to the write task
                 await encode_ahead.acquire()
                 if view_parts is not None and block is None:
-                    mv = await view_parts(part_bytes, stage_size)
+                    with obs_tracing.span("writer.receive") as receive:
+                        mv = await view_parts(part_bytes, stage_size)
+                        receive.end(bytes=0 if mv is None else len(mv))
                     if mv is None:
                         view_parts = None  # tail/unmappable: byte path
                     else:
@@ -382,8 +388,10 @@ class FileWriteBuilder:
                 if block is None:
                     block = np.empty((stage_size, d, chunk),
                                      dtype=np.uint8)
-                got = await aio.read_exact_into(
-                    reader, memoryview(block[len(lens)].reshape(-1)))
+                with obs_tracing.span("writer.receive") as receive:
+                    got = await aio.read_exact_into(
+                        reader, memoryview(block[len(lens)].reshape(-1)))
+                    receive.end(bytes=got)
                 if got == 0:
                     sem.release()
                     encode_ahead.release()

@@ -586,45 +586,51 @@ def make_app(cluster: Cluster,
         connection exactly like the reassembly path's mid-stream
         abort."""
         try:
-            f = await asyncio.to_thread(open, path, "rb")
+            # cancel-safe hop: a handler cancelled mid-open (server
+            # shutdown) must not orphan the handle
+            f = await aio.open_in_thread(lambda: open(path, "rb"),
+                                         lambda h: h.close())
         except OSError:
             return None
         try:
-            resp = web.StreamResponse(status=status, headers=headers)
-            await resp.prepare(request)
-            transport = request.transport
-            if transport is None:  # client already gone
-                return resp
-            loop = asyncio.get_running_loop()
-            try:
+            with obs_tracing.span("gateway.get.send", bytes=count):
+                resp = web.StreamResponse(status=status, headers=headers)
+                await resp.prepare(request)
+                transport = request.transport
+                if transport is None:  # client already gone
+                    return resp
+                loop = asyncio.get_running_loop()
                 try:
-                    await loop.sendfile(transport, f, offset, count)
-                except NotImplementedError:
-                    # no OS sendfile on this transport: bounded chunked
-                    # copy through the normal writer
-                    await asyncio.to_thread(f.seek, offset)
-                    remaining = count
-                    while remaining > 0:
-                        data = await asyncio.to_thread(
-                            f.read, min(1 << 20, remaining))
-                        if not data:
-                            break
-                        remaining -= len(data)
-                        await resp.write(data)
-            except (ConnectionError, OSError) as err:
-                # the file side verified before we got here, so this is
-                # the socket: abort the connection like the reassembly
-                # path does mid-stream
-                log.error("GET %s sendfile aborted: %s",
-                          request.path, err)
-                resp.force_close()
-                if request.transport is not None:
-                    request.transport.close()
+                    try:
+                        await loop.sendfile(transport, f, offset, count)
+                    except NotImplementedError:
+                        # no OS sendfile on this transport: bounded chunked
+                        # copy through the normal writer
+                        await asyncio.to_thread(f.seek, offset)
+                        remaining = count
+                        while remaining > 0:
+                            data = await asyncio.to_thread(
+                                f.read, min(1 << 20, remaining))
+                            if not data:
+                                break
+                            remaining -= len(data)
+                            await resp.write(data)
+                except (ConnectionError, OSError) as err:
+                    # the file side verified before we got here, so this is
+                    # the socket: abort the connection like the reassembly
+                    # path does mid-stream
+                    log.error("GET %s sendfile aborted: %s",
+                              request.path, err)
+                    resp.force_close()
+                    if request.transport is not None:
+                        request.transport.close()
+                    return resp
+                await resp.write_eof()
                 return resp
-            await resp.write_eof()
-            return resp
         finally:
-            await asyncio.to_thread(f.close)
+            # shielded: a handler cancelled here (server shutdown) would
+            # otherwise cancel the queued close and drop the open handle
+            await asyncio.shield(asyncio.to_thread(f.close))
 
     def _serve_source(file_ref: FileReference, cache,
                       seek: int, length: int) -> str:
@@ -798,10 +804,13 @@ def make_app(cluster: Cluster,
         request["cb_source"] = _serve_source(file_ref, cache,
                                              builder.seek, length)
         resp = web.StreamResponse(status=status, headers=headers)
-        await resp.prepare(request)
+        with obs_tracing.span("gateway.get.send"):
+            await resp.prepare(request)
         try:
             async for chunk in builder.stream():
-                await resp.write(chunk)
+                with obs_tracing.span("gateway.get.send",
+                                      bytes=len(chunk)):
+                    await resp.write(chunk)
         except ChunkyBitsError as err:
             # Degraded beyond repair (>p chunks gone) or a storage-node
             # failure mid-file.  Status and Content-Length are already on
@@ -816,7 +825,8 @@ def make_app(cluster: Cluster,
             if request.transport is not None:
                 request.transport.close()
             return resp
-        await resp.write_eof()
+        with obs_tracing.span("gateway.get.send"):
+            await resp.write_eof()
         return resp
 
     def put_reject(status: int, text: str) -> web.Response:
@@ -847,47 +857,53 @@ def make_app(cluster: Cluster,
         # actually waits — it stays as the invariant backstop.  Write
         # grants are deferred while client reads queue (priority:
         # reads > writes), bounded by the scheduler's wait deadline.
-        if qos_sched is not None:
-            cost = int(declared) if declared is not None else None
-            try:
-                # lint: lock-discipline-ok a failed acquire grants no
-                # slot (shed/cancel paths hold nothing to release);
-                # granted slots release in the try/finally just below
-                await qos_sched.acquire("write", request["cb_tenant"],
-                                        cost=cost)
-            except qos_shed_exc:
-                put_reject_counter.labels(reason="shed").inc()
-                resp = put_reject(
-                    503, "error: too many in-flight writes\n")
-                resp.headers["Retry-After"] = _retry_after()
-                return resp
-        try:
-            # A rejected/aborted ingest can leave orphaned shards; they
-            # are content-addressed (possibly shared with other files),
-            # so they are left for the reference-checking
-            # find-unused-hashes GC rather than deleted blindly.
-            async with put_sem:
-                try:
-                    await cluster.write_file(
-                        path,
-                        _GuardedBody(request.content, max_put_bytes,
-                                     min_put_rate),
-                        profile, content_type)
-                except _BodyTooLarge:
-                    put_reject_counter.labels(reason="too_large").inc()
-                    return put_reject(413, "error: body too large\n")
-                except _BodyTooSlow:
-                    put_reject_counter.labels(reason="too_slow").inc()
-                    return put_reject(408, "error: ingest too slow\n")
-                except ChunkyBitsError as err:
-                    log.error("PUT %s failed: %s", path, err)
-                    log.error("location health at failure: %s",
-                              health.stats())
-                    put_reject_counter.labels(reason="error").inc()
-                    return put_reject(500, "error: internal error\n")
-        finally:
+        # admission (the QoS write grant, then an ingest slot) is one
+        # span, ended early once the slot is held; the ``with`` closes
+        # it on every other way out (shed, cancel, error)
+        with obs_tracing.span("gateway.put.admit") as admit:
             if qos_sched is not None:
-                qos_sched.release("write")
+                cost = int(declared) if declared is not None else None
+                try:
+                    # lint: lock-discipline-ok a failed acquire grants no
+                    # slot (shed/cancel paths hold nothing to release);
+                    # granted slots release in the try/finally below
+                    await qos_sched.acquire("write", request["cb_tenant"],
+                                            cost=cost)
+                except qos_shed_exc:
+                    admit.end("shed")
+                    put_reject_counter.labels(reason="shed").inc()
+                    resp = put_reject(
+                        503, "error: too many in-flight writes\n")
+                    resp.headers["Retry-After"] = _retry_after()
+                    return resp
+            try:
+                # A rejected/aborted ingest can leave orphaned shards;
+                # they are content-addressed (possibly shared with other
+                # files), so they are left for the reference-checking
+                # find-unused-hashes GC rather than deleted blindly.
+                async with put_sem:
+                    admit.end()
+                    try:
+                        await cluster.write_file(
+                            path,
+                            _GuardedBody(request.content, max_put_bytes,
+                                         min_put_rate),
+                            profile, content_type)
+                    except _BodyTooLarge:
+                        put_reject_counter.labels(reason="too_large").inc()
+                        return put_reject(413, "error: body too large\n")
+                    except _BodyTooSlow:
+                        put_reject_counter.labels(reason="too_slow").inc()
+                        return put_reject(408, "error: ingest too slow\n")
+                    except ChunkyBitsError as err:
+                        log.error("PUT %s failed: %s", path, err)
+                        log.error("location health at failure: %s",
+                                  health.stats())
+                        put_reject_counter.labels(reason="error").inc()
+                        return put_reject(500, "error: internal error\n")
+            finally:
+                if qos_sched is not None:
+                    qos_sched.release("write")
         return web.Response(status=200)
 
     @web.middleware
@@ -901,11 +917,13 @@ def make_app(cluster: Cluster,
         length: an aborted stream still logs the length it promised
         (the abort itself is logged separately).
 
-        When ``tunables.trace_slow_ms`` arms tracing, this middleware
-        is also the trace root: it mints (or accepts via
-        ``X-Chunky-Trace``) the request's trace id and parks the trace
-        in the context — every task the handler spawns inherits it,
-        and pipeline jobs carry it across the thread boundary."""
+        When a span sink is on (``tunables.trace_slow_ms`` arms the
+        slowest-N buffer; a JAX profiler session records every span),
+        this middleware is also the trace root: it mints (or accepts via
+        ``X-Chunky-Trace``) the request's trace id, parks the trace in
+        the context — every task the handler spawns inherits it, and
+        pipeline jobs carry it across the thread boundary — and opens
+        the request's ``gateway.<method>`` span (obs/tracing.py)."""
         start = time.monotonic()
         status = 500
         nbytes = 0
@@ -917,10 +935,12 @@ def make_app(cluster: Cluster,
             request["cb_tenant"] = gw_qos.resolve_request_tenant(
                 qos_sched.config, request)
         trace = token = None
-        if trace_slow_s > 0:
+        if trace_slow_s > 0 or obs_tracing.profiling():
             trace_id = obs_tracing.clean_id(
                 request.headers.get("X-Chunky-Trace"))
-            trace, token = obs_tracing.start(trace_id)
+            trace, token = obs_tracing.start(trace_id,
+                                             keep=trace_slow_s > 0)
+        envelope = obs_tracing.span("gateway." + request.method.lower())
         try:
             resp = await handler(request)
             status = resp.status
@@ -946,9 +966,10 @@ def make_app(cluster: Cluster,
                     qos_sched.note_request("read", duration)
                 elif request.method == "PUT":
                     qos_sched.note_request("write", duration)
+            envelope.end(str(status), status=status,
+                         bytes=(request.content_length or 0)
+                         if request.method == "PUT" else nbytes)
             if trace is not None and token is not None:
-                trace.add("request", "gateway", start, duration,
-                          str(status))
                 obs_tracing.finish(
                     trace, token, duration=duration,
                     slow_s=trace_slow_s,

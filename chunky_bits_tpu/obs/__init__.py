@@ -7,8 +7,9 @@ behind every existing stat source (chunk cache, host pipeline, health
 scoreboard, scrub daemon, the gateway access log), exposed as
 Prometheus text at gateway ``GET /metrics`` and JSON at ``GET /stats``;
 ``obs.tracing`` follows one request across the async plane, the host
-pipeline's worker threads, and the network fetches, into a bounded
-slowest-N buffer served at ``GET /debug/traces``.
+pipeline's worker threads, and the network fetches, with one span per
+layer boundary: into the JAX profiler's trace while a session records,
+and into a bounded slowest-N buffer served at ``GET /debug/traces``.
 
 ``obs.slo`` is the windowed layer on top of the registry: burn-rate
 SLO rules over a bounded snapshot ring, the pending→firing→resolved

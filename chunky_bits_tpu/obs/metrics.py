@@ -4,10 +4,13 @@ One process-wide, thread-safe registry (worker threads record too, so
 this module is deliberately NOT loop-bound — same contract as
 cluster/health.py) is the single sink behind the existing stat sources:
 
-* **event-recorded series** — ``Profiler.log_request``/``log_read``/
-  ``log_write`` feed latency histograms and byte counters through the
-  :func:`record_request` / :func:`record_io` helpers; the gateway's
-  admission/shed counters increment registry counters directly;
+* **event-recorded series** — ``Profiler.log_request`` and every
+  location read/write (``file/location.py``) feed latency histograms
+  and byte counters through the :func:`record_request` /
+  :func:`record_io` helpers; the codec batchers and dispatch paths
+  count parts, dispatches and transfer bytes (:func:`record_batch`,
+  :func:`record_codec_transfer`); the gateway's admission/shed
+  counters increment registry counters directly;
 * **polled sources** — ``ChunkCache``, ``HostPipeline``,
   ``HealthScoreboard``, ``ScrubDaemon`` and ``RepairPlanner``
   self-register (weakly) at construction and are snapshot at scrape
@@ -379,7 +382,8 @@ def record_request(method: str, status: int, nbytes: int,
 
 
 def record_io(op: str, ok: bool, nbytes: int, duration: float) -> None:
-    """One location I/O completion (``Profiler.log_read``/``log_write``)."""
+    """One location I/O completion (``file/location.py``: every read and
+    write, with or without a ``Profiler``)."""
     op = op if op in ("read", "write") else "other"
     ok_label = "true" if ok else "false"
     _event_family("io_seconds", lambda reg: reg.histogram(
@@ -389,6 +393,29 @@ def record_io(op: str, ok: bool, nbytes: int, duration: float) -> None:
         _event_family("io_bytes", lambda reg: reg.counter(
             "cb_io_bytes_total", "location I/O bytes moved",
             labels=("op",))).labels(op=op).inc(max(nbytes, 0))
+
+
+def record_batch(op: str, parts: int = 0, dispatches: int = 0) -> None:
+    """One coalesced group of a codec batcher (``ops/batching.py``): the
+    parts it carried and the codec dispatches it issued, so parts per
+    dispatch reads off two counters."""
+    op = op if op in ("encode", "reconstruct") else "other"
+    _event_family("batch_parts", lambda reg: reg.counter(
+        "cb_batch_parts_total", "parts carried by codec batcher groups",
+        labels=("op",))).labels(op=op).inc(parts)
+    _event_family("batch_dispatches", lambda reg: reg.counter(
+        "cb_batch_dispatches_total", "codec dispatches issued by batchers",
+        labels=("op",))).labels(op=op).inc(dispatches)
+
+
+def record_codec_transfer(direction: str, nbytes: int) -> None:
+    """Codec bytes moved between host and device (``h2d``: a
+    ``device_put`` of shards; ``d2h``: a materialized result)."""
+    direction = direction if direction in ("h2d", "d2h") else "other"
+    _event_family("codec_transfer", lambda reg: reg.counter(
+        "cb_codec_transfer_bytes_total",
+        "codec bytes moved between host and device",
+        labels=("dir",))).labels(dir=direction).inc(max(nbytes, 0))
 
 
 def record_build_info(version: str, backend: str, flags: dict,

@@ -1,30 +1,62 @@
-"""Two-plane request tracing: trace IDs, spans, slowest-N buffer.
+"""Request spans: one API, two sinks.
 
-Answers "why was this p999 request slow" with WHICH PLANE ate the time:
-the gateway's access-log middleware mints a trace ID (or accepts the
-client's via ``X-Chunky-Trace``) and parks the active
-:class:`Trace` in a ``contextvars.ContextVar`` — asyncio copies the
-context into every task, so the trace follows the request through
-``FileReadBuilder.stream``'s prefetch tasks, ``FilePart.read_buffers``'s
-hedged fetch races, and the reconstruct path with zero explicit
-plumbing.  The one boundary contextvars cannot cross — the host
-pipeline's worker threads — is bridged by capture-at-submit:
-``_Job.__init__`` snapshots :func:`current` on the submitting thread and
-the job runner records queue-wait and execution spans onto that trace
-from the worker (``Trace.add`` is thread-safe).
+:func:`span` marks one boundary of the served path — a context manager
+that also works across ``await`` — opened when the work starts and
+closed when it ends.  Where it lands:
 
-Spans are flat ``(name, plane, start, duration, outcome)`` records —
-planes: ``gateway`` (the request envelope), ``network`` (chunk
-fetches / location I/O), ``host`` (pipeline queue wait + compute),
-``compute`` (erasure reconstruct dispatch) — enough to attribute a slow
-request without the weight of a span tree.
+* **The profiler's trace.**  While a JAX profiler session is active in
+  the process (``jax.profiler.start_trace``), every span is a
+  ``TraceAnnotation`` carrying its name and short args (``req``,
+  ``bytes``, ``parts``, ...), on the same clock as the device's
+  operations in the same ``.xplane.pb``.  The session itself is the
+  switch: there is no option.  This module never imports jax; it asks
+  the profiler only once something else has imported it.
+* **The slowest-N buffer.**  With ``tunables.trace_slow_ms`` /
+  ``$CHUNKY_BITS_TPU_TRACE_SLOW_MS`` > 0 (read at gateway app build),
+  each request's spans are kept on its :class:`Trace` as flat
+  ``(name, plane, start, duration, outcome)`` records, and requests at
+  least that slow are filed in the buffer served at gateway
+  ``GET /debug/traces`` with per-plane totals.  Planes: ``gateway``
+  (the request envelope), ``queue`` (admission, batcher and pipeline
+  waits), ``network`` (client socket, chunk and metadata I/O),
+  ``compute`` (encode and reconstruct), ``host`` (pipeline jobs),
+  ``device`` (codec dispatches).
 
-**Opt-in, measured-before-defaulting**: tracing arms only when
-``tunables.trace_slow_ms`` / ``$CHUNKY_BITS_TPU_TRACE_SLOW_MS`` > 0
-(the gateway reads it at app build).  Off, the only cost anywhere is a
-ContextVar.get returning the None default.  On, completed traces at
-least ``trace_slow_ms`` slow enter the process-wide slowest-N buffer
-served at gateway ``GET /debug/traces``.
+Request identity: the gateway's access-log middleware mints a trace id
+(or accepts the client's via ``X-Chunky-Trace``) whenever either sink is
+on and parks the request's :class:`Trace` in a ``ContextVar``; asyncio
+copies the context into every task the handler spawns, and the host
+pipeline carries it across its worker threads by capture-at-submit
+(``_Job.__init__``), so every span of a request carries its id as
+``req``.  Spans serving many requests (batcher groups, their codec
+dispatches) run in a context of their own and carry ``parts`` instead.
+
+Off — no profiler session, ``trace_slow_ms`` 0 — a span costs one
+``ContextVar.get`` and at most one enabled-check, and returns a shared
+no-op object.
+
+The span catalog (names are stable: benchmark readers select by name):
+
+==========================  ==============================================
+``gateway.<method>``        one request, middleware to response (req,
+                            bytes, status)
+``gateway.put.admit``       PUT admission: QoS write grant, ingest slot
+``gateway.get.send``        one body write to the client
+``writer.receive``          the read loop waiting on body bytes (bytes)
+``writer.encode``           a staged block's parity and digests (parts,
+                            bytes)
+``meta.write``/``meta.read``  file-reference publish (with fsync) / load
+``dest.write``              one chunk write at its location (bytes)
+``reader.fetch``            one chunk's verified fetch (bytes)
+``reader.reconstruct``      a part's await on the decode batcher
+``batching.<op>.queue``     batcher submit to group start
+``batching.<op>``           one coalesced group (parts, bytes)
+``host.<stage>[.queue]``    a host-pipeline job / its queue wait
+``codec.h2d``               host-to-device transfer (bytes)
+``codec.launch``            the jitted codec call
+``codec.collect``           device wait plus device-to-host copy (bytes)
+``codec.hash_join``         the wait for ingest hash jobs
+==========================  ==============================================
 """
 
 from __future__ import annotations
@@ -33,21 +65,20 @@ import contextvars
 import heapq
 import itertools
 import os
+import sys
 import threading
 from typing import Optional
 
 #: the clock seam (canonical surface cluster/clock.py; utils-side
-#: import for cycle hygiene): span starts arrive off this clock
-#: (file_part.py, gateway middleware), so the trace birth stamp the
-#: offsets subtract from must come off the SAME clock — inside a
-#: virtual-time simulation a real-clock t0 would turn every start_ms
-#: into timebase-mixed garbage
+#: import for cycle hygiene): slowest-N span starts come off this
+#: clock, so the trace birth stamp the offsets subtract from must come
+#: off the SAME clock — inside a virtual-time simulation a real-clock t0
+#: would turn every start_ms into timebase-mixed garbage
 from chunky_bits_tpu.utils import clock as _clock
 
-#: the active trace for this context; None = tracing off / untraced
-#: request.  A ContextVar, not module state: every asyncio task gets
-#: its own copy, worker threads read None unless a job carried a
-#: captured trace.
+#: the active trace for this context; None = untraced.  A ContextVar,
+#: not module state: every asyncio task gets its own copy, worker
+#: threads read None unless a job carried a captured trace.
 _CURRENT: "contextvars.ContextVar[Optional[Trace]]" = \
     contextvars.ContextVar("cb_trace", default=None)
 
@@ -59,8 +90,27 @@ MAX_SPANS = 256
 #: traces kept in the slowest-N buffer
 BUFFER_CAPACITY = 64
 
-#: accepted ``X-Chunky-Trace`` shape: short, printable, header-safe
+#: accepted ``X-Chunky-Trace`` shape: short, printable, header-safe,
+#: and free of the profiler's metadata separators (``#``, ``,``, ``=``)
 _MAX_ID_LEN = 64
+_ID_REJECTS = frozenset('"\\#,=')
+
+#: slowest-N plane per span name (full name first, then its first
+#: dotted part); see the module docstring
+# lint: loop-shared-ok a constant table, never written after import
+_PLANES = {
+    "gateway": "gateway", "gateway.put.admit": "queue",
+    "gateway.get.send": "network", "writer.receive": "network",
+    "writer.encode": "compute", "meta": "network", "dest": "network",
+    "reader.fetch": "network", "reader.reconstruct": "compute",
+    "batching": "compute", "host": "host", "codec": "device",
+}
+
+
+def _plane(name: str) -> str:
+    if name.endswith(".queue"):
+        return "queue"
+    return _PLANES.get(name) or _PLANES.get(name.split(".", 1)[0], "host")
 
 
 class Span:
@@ -82,14 +132,17 @@ class Span:
 
 
 class Trace:
-    """One request's span collection.  ``add`` is thread-safe: loop
+    """One request's identity and, when ``keep`` (the slowest-N buffer
+    is armed), its span collection.  ``add`` is thread-safe: loop
     callbacks, hedge tasks AND pipeline worker threads all record onto
     the same trace."""
 
-    __slots__ = ("trace_id", "t0", "spans", "dropped_spans", "_lock")
+    __slots__ = ("trace_id", "keep", "t0", "spans", "dropped_spans",
+                 "_lock")
 
-    def __init__(self, trace_id: str) -> None:
+    def __init__(self, trace_id: str, keep: bool = True) -> None:
         self.trace_id = trace_id
+        self.keep = keep
         self.t0 = _clock.monotonic()
         self.spans: list[Span] = []
         self.dropped_spans = 0
@@ -99,6 +152,8 @@ class Trace:
             outcome: str = "ok") -> None:
         """Record one span; ``start`` is a clock-seam ``monotonic()``
         stamp (converted to ms offset from the trace's birth)."""
+        if not self.keep:
+            return
         span = Span(name, plane, (start - self.t0) * 1000.0,
                     duration * 1000.0, outcome)
         with self._lock:
@@ -180,40 +235,141 @@ def clean_id(raw: Optional[str]) -> str:
     if raw:
         raw = raw.strip()
         if 0 < len(raw) <= _MAX_ID_LEN and raw.isprintable() \
-                and '"' not in raw and "\\" not in raw:
+                and not _ID_REJECTS.intersection(raw):
             return raw
     return mint_id()
 
 
-def start(trace_id: str) -> tuple["Trace", "contextvars.Token"]:
-    """Open a trace and make it current; pair with :func:`finish`."""
-    trace = Trace(trace_id)
+def start(trace_id: str, keep: bool = True
+          ) -> tuple["Trace", "contextvars.Token"]:
+    """Open a trace and make it current; pair with :func:`finish`.
+    ``keep`` arms the slowest-N sink for it (else it is the request's
+    identity for the profiler's trace only)."""
+    trace = Trace(trace_id, keep)
     token = _CURRENT.set(trace)
     return trace, token
 
 
 def finish(trace: "Trace", token: "contextvars.Token", *,
            duration: float, slow_s: float, meta: dict) -> bool:
-    """Close out a trace: restore the context and, when the request ran
-    at least ``slow_s``, file it in the slowest-N buffer.  Returns
-    whether the trace was retained."""
+    """Close out a trace: restore the context and, when it is kept and
+    the request ran at least ``slow_s``, file it in the slowest-N
+    buffer.  Returns whether the trace was retained."""
     _CURRENT.reset(token)
     duration_ms = duration * 1000.0
-    if duration < slow_s:
+    if not trace.keep or duration < slow_s:
         return False
     return _BUFFER.offer(duration_ms,
                          trace.to_obj(duration_ms, dict(meta)))
 
 
 def current() -> Optional["Trace"]:
-    """The context's active trace, or None (tracing off — the one-call
+    """The context's active trace, or None (untraced — the one-call
     fast path every instrumented site pays)."""
     return _CURRENT.get()
 
 
-def record_span(name: str, plane: str, start_t: float, duration: float,
-                outcome: str = "ok") -> None:
-    """Record a span onto the context's trace; no-op when untraced."""
+class _Bound:
+    __slots__ = ("_trace", "_token")
+
+    def __init__(self, trace: "Trace") -> None:
+        self._trace = trace
+        self._token = None
+
+    def __enter__(self) -> "_Bound":
+        self._token = _CURRENT.set(self._trace)
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        _CURRENT.reset(self._token)
+        return False
+
+
+def bind(trace: Optional["Trace"]):
+    """Make ``trace`` current on this thread for a ``with`` block — a
+    pipeline job running on a worker for the request that submitted
+    it.  ``None`` leaves the context alone."""
+    return _NOOP if trace is None else _Bound(trace)
+
+
+#: ``jax.profiler.TraceAnnotation`` once jax is imported (resolved on
+#: first sight, never imported from here)
+_ANNOTATION = None
+
+
+def profiling() -> bool:
+    """Whether a JAX profiler session is recording in this process."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        prof = getattr(sys.modules.get("jax"), "profiler", None)
+        if prof is None:
+            return False
+        _ANNOTATION = prof.TraceAnnotation
+    return _ANNOTATION.is_enabled()
+
+
+class _NoSpan:
+    """The shared span of the off path."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "_NoSpan":
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+    def end(self, outcome: str = "ok", **args) -> None:
+        pass
+
+
+_NOOP = _NoSpan()
+
+
+class _Span:
+    """A live span: the profiler annotation (open since construction)
+    and/or the slowest-N record.  ``end`` closes it once; a span may
+    close on another thread than the one that opened it (queue waits)."""
+
+    __slots__ = ("name", "_trace", "_start", "_annotation")
+
+    def __init__(self, name: str, trace: Optional["Trace"],
+                 profiled: bool, args: dict) -> None:
+        self.name = name
+        self._trace = trace if trace is not None and trace.keep else None
+        self._start = _clock.monotonic() if self._trace is not None \
+            else 0.0
+        self._annotation = None
+        if profiled:
+            if trace is not None:
+                args["req"] = trace.trace_id
+            self._annotation = _ANNOTATION(name, **args)
+
+    def __enter__(self) -> "_Span":
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self.end("ok" if exc_type is None else "error")
+        return False
+
+    def end(self, outcome: str = "ok", **args) -> None:
+        """Close the span; ``args`` known only at the end (a status, a
+        byte count) join the annotation's."""
+        annotation, self._annotation = self._annotation, None
+        if annotation is not None:
+            if args:
+                annotation.set_metadata(**args)
+            annotation.__exit__(None, None, None)
+        trace, self._trace = self._trace, None
+        if trace is not None:
+            trace.add(self.name, _plane(self.name), self._start,
+                      _clock.monotonic() - self._start, outcome)
+
+
+def span(name: str, **args):
+    """A span of the context's request; see the module docstring."""
     trace = _CURRENT.get()
-    if trace is not None:
-        trace.add(name, plane, start_t, duration, outcome)
+    profiled = profiling()
+    if not profiled and (trace is None or not trace.keep):
+        return _NOOP
+    return _Span(name, trace, profiled, args)

@@ -32,6 +32,7 @@ from typing import Callable, Optional, Sequence
 import numpy as np
 
 from chunky_bits_tpu.errors import ErasureError
+from chunky_bits_tpu.obs import tracing as obs_tracing
 from chunky_bits_tpu.ops import gf256, matrix
 
 
@@ -305,7 +306,8 @@ class ErasureCoder:
 
             jobs = get_host_pipeline().hash_rows_jobs(data, data_digests)
             parity = self.encode_batch(data)
-            join_jobs(jobs)
+            with obs_tracing.span("codec.hash_join"):
+                join_jobs(jobs)
         else:
             parity = self.encode_batch(data)
             hash_rows(data, data_digests)
@@ -370,7 +372,8 @@ class ErasureCoder:
         out = []
         for ticket, jobs, covered, data_digests, parity_digests in staged:
             parity = ticket.result()
-            join_jobs(jobs)
+            with obs_tracing.span("codec.hash_join"):
+                join_jobs(jobs)
             if not covered.all():
                 # rows the callback never saw (mid-run degrade's CPU
                 # recompute) hash from the parity actually returned

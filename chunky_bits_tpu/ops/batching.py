@@ -18,11 +18,14 @@ lone request is dispatched immediately.
 from __future__ import annotations
 
 import asyncio
+import contextvars
 from typing import Optional, Sequence
 
 import numpy as np
 
 from chunky_bits_tpu.errors import ErasureError
+from chunky_bits_tpu.obs import metrics as obs_metrics
+from chunky_bits_tpu.obs import tracing as obs_tracing
 from chunky_bits_tpu.ops.backend import get_coder
 
 
@@ -43,9 +46,20 @@ class _CoalescingBatcher:
 
     Instances are per-pipeline (one read stream, one resilver run, one
     cluster ingest scope) and must be used from a single event loop.
-    Subclasses implement ``_run_group(key, payloads) -> results`` (called
-    in a worker thread).
+    Subclasses name their ``op`` and implement ``_size(key, payloads)
+    -> (parts, bytes)`` and ``_run_group(key, payloads) -> results``
+    (called in a worker thread).
+
+    Spans (obs/tracing.py): each request's wait from submit to its
+    group's start is ``batching.<op>.queue``, on the request; each group
+    is ``batching.<op>`` with its parts and bytes.  A group serves many
+    requests, so the drain and its dispatches run in a context of their
+    own: their spans, and the codec and host spans under them, carry no
+    request id.
     """
+
+    #: "encode" | "reconstruct": span names and counter labels
+    op = ""
 
     #: ``_pending``/``_task``/``_inflight`` bookkeeping is lock-free
     #: because it never leaves the owning loop's thread; the CB204
@@ -58,7 +72,8 @@ class _CoalescingBatcher:
     def __init__(self, backend: Optional[str] = None, max_batch: int = 128):
         self.backend = backend
         self.max_batch = max_batch
-        self._pending: list[tuple[tuple, object, asyncio.Future]] = []
+        self._pending: list[tuple[tuple, object, asyncio.Future,
+                                 object]] = []
         self._task: Optional[asyncio.Task] = None
         self._inflight: set[asyncio.Task] = set()
         #: codec dispatches issued (merged batches count once; unmerged
@@ -70,9 +85,13 @@ class _CoalescingBatcher:
 
     async def _submit(self, key: tuple, payload):
         fut = asyncio.get_running_loop().create_future()
-        self._pending.append((key, payload, fut))
+        queued = obs_tracing.span(f"batching.{self.op}.queue")
+        self._pending.append((key, payload, fut, queued))
         if self._task is None or self._task.done():
-            self._task = asyncio.create_task(self._drain())
+            # created inside a fresh context, so the task copies that
+            # one (create_task's ``context=`` needs Python 3.11)
+            self._task = contextvars.Context().run(
+                asyncio.create_task, self._drain())
         # lint: unbounded-await-ok resolved (result or exception) by
         # _dispatch in every outcome; a failed device dispatch raises
         # into it rather than parking
@@ -99,19 +118,26 @@ class _CoalescingBatcher:
 
     async def _dispatch(self, key: tuple, group: list) -> None:
         self.groups += 1
+        payloads = [g[1] for g in group]
+        for item in group:
+            item[3].end()
         try:
-            results = await asyncio.to_thread(
-                self._run_group, key, [g[1] for g in group])
+            parts, nbytes = self._size(key, payloads)
+            obs_metrics.record_batch(self.op, parts=parts)
+            with obs_tracing.span(f"batching.{self.op}", parts=parts,
+                                  bytes=nbytes):
+                results = await asyncio.to_thread(
+                    self._run_group, key, payloads)
         # lint: broad-except-ok delivered to every waiter via
         # fut.set_exception; CancelledError additionally re-raised
         except BaseException as err:
-            for _, _, fut in group:
+            for _, _, fut, _ in group:
                 if not fut.done():
                     fut.set_exception(err)
             if isinstance(err, asyncio.CancelledError):
                 raise
         else:
-            for (_, _, fut), res in zip(group, results):
+            for (_, _, fut, _), res in zip(group, results):
                 if not fut.done():
                     if isinstance(res, _GroupItemError):
                         fut.set_exception(res.err)
@@ -134,6 +160,13 @@ class _CoalescingBatcher:
                 return
             await asyncio.gather(*tasks, return_exceptions=True)
 
+    def _count_dispatches(self, n: int) -> None:
+        self.dispatches += n
+        obs_metrics.record_batch(self.op, dispatches=n)
+
+    def _size(self, key: tuple, payloads: list) -> tuple[int, int]:
+        raise NotImplementedError
+
     def _run_group(self, key: tuple, payloads: list) -> list:
         raise NotImplementedError
 
@@ -145,6 +178,8 @@ class ReconstructBatcher(_CoalescingBatcher):
     file degraded by the same node loss share a pattern and rebuild in one
     ``[B, d+p, S]`` dispatch through ``ErasureCoder.reconstruct_batch``.
     """
+
+    op = "reconstruct"
 
     async def reconstruct(
         self, d: int, p: int, arrays: Sequence[Optional[np.ndarray]],
@@ -180,9 +215,13 @@ class ReconstructBatcher(_CoalescingBatcher):
         key = (d, p, present, wanted, size, code)
         return await self._submit(key, arrays)
 
+    def _size(self, key: tuple, requests: list) -> tuple[int, int]:
+        d, size = key[0], key[4]
+        return len(requests), len(requests) * d * size
+
     def _run_group(self, key: tuple, requests: list[list]) -> list[list]:
         d, p, present, wanted, size, code = key
-        self.dispatches += 1
+        self._count_dispatches(1)
         coder = get_coder(d, p, self.backend, code)
         # stack straight into decode layout (the first d present rows,
         # ascending) — one gather pass instead of a full [B, d+p, S]
@@ -234,10 +273,16 @@ class EncodeHashBatcher(_CoalescingBatcher):
     pipeline internally).
     """
 
+    op = "encode"
+
     def __init__(self, backend: Optional[str] = None, max_batch: int = 128,
                  host_pipeline: Optional[object] = None):
         super().__init__(backend, max_batch)
         self.host_pipeline = host_pipeline
+
+    def _size(self, key: tuple, batches: list) -> tuple[int, int]:
+        return (sum(b.shape[0] for b in batches),
+                sum(b.nbytes for b in batches))
 
     async def encode_hash(
         self, d: int, p: int, stacked: np.ndarray, code: str = "rs",
@@ -284,7 +329,7 @@ class EncodeHashBatcher(_CoalescingBatcher):
             # memcpy; host hashing overlaps inside
             # encode_hash_batches, so the host_pipeline slicing path
             # is deliberately bypassed here.
-            self.dispatches += len(batches)
+            self._count_dispatches(len(batches))
             return coder.encode_hash_batches(batches)
         if not merge or len(batches) == 1:
             # Unmerged batches are independent dispatches that happen to
@@ -292,7 +337,7 @@ class EncodeHashBatcher(_CoalescingBatcher):
             # only, and later batches in the group must still encode.
             out = []
             for b in batches:
-                self.dispatches += 1
+                self._count_dispatches(1)
                 try:
                     out.append(self._encode(coder, b))
                 # lint: broad-except-ok re-raised at the owning waiter
@@ -300,7 +345,7 @@ class EncodeHashBatcher(_CoalescingBatcher):
                 except Exception as err:
                     out.append(_GroupItemError(err))
             return out
-        self.dispatches += 1
+        self._count_dispatches(1)
         merged = np.concatenate(batches, axis=0)
         parity, digests = self._encode(coder, merged)
         out = []

@@ -34,6 +34,9 @@ import threading
 from dataclasses import dataclass
 from typing import Callable, Optional
 
+from chunky_bits_tpu.obs import metrics as obs_metrics
+from chunky_bits_tpu.obs import tracing as obs_tracing
+
 #: classic double buffer: one dispatch computing, one staging
 DEFAULT_DEPTH = 2
 
@@ -157,7 +160,12 @@ class DispatchPipeline:
     def _materialize_oldest_locked(self) -> None:
         e = self._window.pop(0)
         try:
-            e.value = e.materialize(e.handle)
+            # the device wait plus the device->host copy (obs/tracing.py)
+            with obs_tracing.span("codec.collect") as collect:
+                e.value = e.materialize(e.handle)
+                nbytes = getattr(e.value, "nbytes", 0)
+                collect.end(bytes=nbytes)
+            obs_metrics.record_codec_transfer("d2h", nbytes)
             e.state = _DONE
             self._stats.completed += 1
         except BaseException as err:

@@ -31,6 +31,8 @@ from collections import OrderedDict
 import numpy as np
 
 from chunky_bits_tpu.errors import ErasureError
+from chunky_bits_tpu.obs import metrics as obs_metrics
+from chunky_bits_tpu.obs import tracing as obs_tracing
 from chunky_bits_tpu.ops import gf256
 from chunky_bits_tpu.ops.backend import ErasureBackend
 
@@ -64,7 +66,12 @@ def _jitted_apply():
     jax, _ = _ensure_jax()
     from chunky_bits_tpu.ops.bitplane import apply_bitplane
 
-    _APPLY_FN = jax.jit(apply_bitplane)
+    def gf_apply_einsum(m2, shards):
+        # the program's stable name in the device trace
+        # (``jit_gf_apply_einsum``)
+        return apply_bitplane(m2, shards)
+
+    _APPLY_FN = jax.jit(gf_apply_einsum)
     return _APPLY_FN
 
 
@@ -149,29 +156,51 @@ class JaxBackend(ErasureBackend):
         post-processing (shard hashing) with the remaining device work,
         on the caller's thread.  ``dispatch`` may return one array or a tuple of arrays (the
         fused encode+hash path); tuple outputs are concatenated per
-        element, and ``on_block`` must be None for them."""
+        element, and ``on_block`` must be None for them.
+
+        Each block is three spans (obs/tracing.py): ``codec.h2d`` (the
+        ``device_put``), ``codec.launch`` (the jitted call, which only
+        enqueues) and ``codec.collect`` (the wait for the device and the
+        device-to-host copy)."""
         jax, _ = _ensure_jax()
+
+        def put(x):
+            with obs_tracing.span("codec.h2d", bytes=x.nbytes):
+                dev = jax.device_put(x)
+            obs_metrics.record_codec_transfer("h2d", x.nbytes)
+            return dev
+
+        def launch(dev):
+            with obs_tracing.span("codec.launch"):
+                return dispatch(dev)
 
         def materialize(o):
             # the device->host copy of a TPU array can come back strided
             # (seen for [B, 3, S] uint8 parity on a v5e); host hashing
             # writes through flat views, so blocks leave here contiguous
-            if isinstance(o, tuple):
-                assert on_block is None
-                return tuple(np.ascontiguousarray(a) for a in o)
-            return np.ascontiguousarray(o)
+            with obs_tracing.span("codec.collect") as collect:
+                if isinstance(o, tuple):
+                    assert on_block is None
+                    out = tuple(np.ascontiguousarray(a) for a in o)
+                    nbytes = sum(a.nbytes for a in out)
+                else:
+                    out = np.ascontiguousarray(o)
+                    nbytes = out.nbytes
+                collect.end(bytes=nbytes)
+            obs_metrics.record_codec_transfer("d2h", nbytes)
+            return out
 
         b = shards.shape[0]
         if b <= block:
-            out = materialize(dispatch(jax.device_put(shards)))
+            out = materialize(launch(put(shards)))
             if on_block is not None:
                 on_block(0, out)
             return out
         outs = []
         pending = []
         for lo in range(0, b, block):
-            dev = jax.device_put(np.ascontiguousarray(shards[lo:lo + block]))
-            pending.append(dispatch(dev))
+            dev = put(np.ascontiguousarray(shards[lo:lo + block]))
+            pending.append(launch(dev))
             if len(pending) > 1:
                 arr = materialize(pending.pop(0))
                 if on_block is not None:
@@ -239,7 +268,7 @@ class JaxBackend(ErasureBackend):
         sha = make_sha256_aligned(s)
         r = mat.shape[0]
 
-        def fused(dev):
+        def gf_encode_sha_pallas(dev):
             b, k, _ = dev.shape
             parity = apply_matrix_pallas(mat, dev, interpret=interpret)
             # lint: jit-hygiene-ok rows are s bytes with s % 128 == 0
@@ -248,7 +277,7 @@ class JaxBackend(ErasureBackend):
                 [dev, parity], axis=1).reshape(b * (k + r), s))
             return parity, digests.reshape(b, k + r, 32)
 
-        fn = jax.jit(fused)
+        fn = jax.jit(gf_encode_sha_pallas)
         with self._lock:
             self._fused_cache[key] = fn
             while len(self._fused_cache) > self.max_cached_matrices:
@@ -330,6 +359,7 @@ class JaxBackend(ErasureBackend):
                 arr, parity_digests[lo:lo + arr.shape[0]]))
 
         parity = self.apply_matrix(mat, shards, on_block=on_block)
-        join_jobs(jobs)
+        with obs_tracing.span("codec.hash_join"):
+            join_jobs(jobs)
         return parity, np.concatenate([data_digests, parity_digests],
                                       axis=1)

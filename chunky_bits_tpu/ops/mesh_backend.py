@@ -53,6 +53,7 @@ from typing import Callable, Optional
 import numpy as np
 
 from chunky_bits_tpu.errors import ErasureError
+from chunky_bits_tpu.obs import tracing as obs_tracing
 from chunky_bits_tpu.ops.backend import ErasureBackend
 from chunky_bits_tpu.ops.dispatch_pipeline import DispatchPipeline
 
@@ -305,6 +306,7 @@ class MeshBackend(ErasureBackend):
                 arr, parity_digests[lo:lo + arr.shape[0]]))
 
         parity = self.apply_matrix(mat, shards, on_block=on_block)
-        join_jobs(jobs)
+        with obs_tracing.span("codec.hash_join"):
+            join_jobs(jobs)
         return parity, np.concatenate([data_digests, parity_digests],
                                       axis=1)

@@ -87,7 +87,7 @@ class _Job:
 
     __slots__ = ("stage", "fn", "nbytes", "result", "error",
                  "_event", "_callbacks", "_lock", "_started",
-                 "trace", "submitted_at")
+                 "trace", "queued")
 
     def __init__(self, stage: str, fn: Callable[[], Any],
                  nbytes: int = 0) -> None:
@@ -101,13 +101,13 @@ class _Job:
         self._lock = threading.Lock()
         self._started = False
         # capture-at-submit: the contextvar trace of the SUBMITTING
-        # thread (None when tracing is off or the submitter is itself a
+        # thread (None when untraced or the submitter is itself a
         # worker) rides the job across the plane boundary so queue-wait
-        # and execution spans land on the request that asked (obs/
-        # tracing.py — one ContextVar.get when tracing is off)
+        # and execution spans land on the request that asked
+        # (obs/tracing.py — a ContextVar.get and the profiler's
+        # enabled-check when no span sink is on)
         self.trace = obs_tracing.current()
-        self.submitted_at = time.monotonic() if self.trace is not None \
-            else 0.0
+        self.queued = obs_tracing.span(f"host.{stage}.queue")
 
     def _claim(self) -> bool:
         """Atomically claim the right to run this job.  Shutdown races
@@ -295,35 +295,33 @@ class HostPipeline:
     def _run_job(self, job: _Job) -> None:
         if not job._claim():
             return  # a racing claimant (shutdown rescue) already ran it
+        # two spans per job: how long it WAITED (the queue —
+        # saturation's signature) and how long it RAN, both on the
+        # request that submitted it (bound here, on the worker, so spans
+        # inside the job carry it too)
+        job.queued.end()
         # lint: clock-escape-ok real worker-thread stage profiling;
         # real thread work has zero virtual width under sim
         t0 = time.perf_counter()
-        # lint: clock-escape-ok trace spans timestamp real host work
-        t0_mono = time.monotonic() if job.trace is not None else 0.0
-        try:
-            job.result = job.fn()
-        # lint: broad-except-ok delivered verbatim to the waiter via
-        # job.error (wait/join_jobs re-raise); nothing is swallowed
-        except BaseException as err:
-            job.error = err
-        finally:
-            # lint: clock-escape-ok real worker-thread stage profiling
-            dt = time.perf_counter() - t0
-            with self._lock:
-                st = self._stages.setdefault(job.stage, [0, 0.0, 0])
-                st[0] += 1
-                st[1] += dt
-                st[2] += job.nbytes
-            if job.trace is not None:
-                # two spans per traced job: how long it WAITED (the
-                # queue — saturation's signature) and how long it RAN
-                job.trace.add(f"queue.{job.stage}", "host",
-                              job.submitted_at,
-                              max(t0_mono - job.submitted_at, 0.0))
-                job.trace.add(f"pipeline.{job.stage}", "host", t0_mono,
-                              dt, "ok" if job.error is None
-                              else "error")
-            job._finish()
+        with obs_tracing.bind(job.trace), \
+                obs_tracing.span(f"host.{job.stage}",
+                                 bytes=job.nbytes) as run:
+            try:
+                job.result = job.fn()
+            # lint: broad-except-ok delivered verbatim to the waiter via
+            # job.error (wait/join_jobs re-raise); nothing is swallowed
+            except BaseException as err:
+                job.error = err
+            finally:
+                # lint: clock-escape-ok real worker-thread stage profiling
+                dt = time.perf_counter() - t0
+                with self._lock:
+                    st = self._stages.setdefault(job.stage, [0, 0.0, 0])
+                    st[0] += 1
+                    st[1] += dt
+                    st[2] += job.nbytes
+                run.end("ok" if job.error is None else "error")
+                job._finish()
 
     def _offer(self, job: _Job) -> None:
         """Queue a job without ever blocking: a full queue, shutdown, or

@@ -34,29 +34,43 @@ from typing import Optional
 
 import numpy as np
 
+from chunky_bits_tpu.obs import metrics as obs_metrics
+from chunky_bits_tpu.obs import tracing as obs_tracing
 from chunky_bits_tpu.ops import gf256
 
 
-def _smap(fn, *, mesh, in_specs, out_specs, impl="einsum"):
+def _smap(fn, *, mesh, in_specs, out_specs, name, impl="einsum"):
     """shard_map with the varying-axes checker disabled for the pallas
     impls: pallas_call's out_shape carries no vma annotation, so jax's
     check_vma rejects it; the out_specs here are explicit and the psum
-    lowers to the same collective either way."""
+    lowers to the same collective either way.  ``name`` is the
+    program's stable name in the device trace (``jit_<name>``)."""
     import jax
 
-    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs,
-                         check_vma=impl == "einsum")
+    mapped = jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs,
+                           check_vma=impl == "einsum")
+
+    def program(*args):
+        return mapped(*args)
+
+    program.__name__ = program.__qualname__ = name
+    return program
 
 
 def _put(mesh, spec, x):
     """Place ``x`` straight into its sharded layout: each device
     receives only its own slice (``jnp.asarray`` would land the whole
-    block on device 0 first and leave XLA to move it across chips)."""
+    block on device 0 first and leave XLA to move it across chips).
+    Spanned as ``codec.h2d`` (obs/tracing.py)."""
     import jax
     from jax.sharding import NamedSharding
 
-    return jax.device_put(x, NamedSharding(mesh, spec))
+    nbytes = getattr(x, "nbytes", 0)
+    with obs_tracing.span("codec.h2d", bytes=nbytes):
+        placed = jax.device_put(x, NamedSharding(mesh, spec))
+    obs_metrics.record_codec_transfer("h2d", nbytes)
+    return placed
 
 
 def _make_mesh_2d(n_devices, first, first_name, second, second_name,
@@ -200,6 +214,7 @@ def _sharded_apply_fn(mesh, impl: str, donate: bool = False):
         mesh=mesh,
         in_specs=(P(None, None), P("dp", None, "sp")),
         out_specs=P("dp", None, "sp"),
+        name="gf_apply_mesh",
         impl=impl,
     ), donate_argnums=(1,) if donate else ())
 
@@ -224,8 +239,9 @@ def sharded_apply(mesh, mat: np.ndarray, shards, *,
         impl = _auto_impl(mesh, r, k, s // mesh.shape["sp"])
     _check_impl(impl)
     m2 = _device_matrix(impl, mat)
-    return _sharded_apply_fn(mesh, impl, donate)(
-        m2, _put(mesh, P("dp", None, "sp"), shards))
+    placed = _put(mesh, P("dp", None, "sp"), shards)
+    with obs_tracing.span("codec.launch"):
+        return _sharded_apply_fn(mesh, impl, donate)(m2, placed)
 
 
 @functools.lru_cache(maxsize=32)
@@ -247,6 +263,7 @@ def _encode_step_fn(mesh, impl: str):
         mesh=mesh,
         in_specs=(P(None, None), P("dp", None, "sp")),
         out_specs=(P("dp", None, "sp"), P()),
+        name="gf_encode_step_mesh",
         impl=impl,
     ))
 
@@ -323,6 +340,7 @@ def _wide_apply_fn(mesh, impl: str, donate: bool = False):
         mesh=mesh,
         in_specs=(m2_spec, P("dp", "tp", None)),
         out_specs=P("dp", None, None),
+        name="gf_apply_mesh_wide",
         impl=impl,
     ), donate_argnums=(1,) if donate else ())
 
@@ -370,8 +388,9 @@ def wide_apply_sharded(mesh, mat: np.ndarray, shards,
     else:
         m2 = jnp.asarray(_host_bitmajor_blocks(mat.tobytes(), r, k, tp),
                          dtype=jnp.int8)
-    return _wide_apply_fn(mesh, impl, donate)(
-        m2, _put(mesh, P("dp", "tp", None), shards))
+    placed = _put(mesh, P("dp", "tp", None), shards)
+    with obs_tracing.span("codec.launch"):
+        return _wide_apply_fn(mesh, impl, donate)(m2, placed)
 
 
 def encode_wide_sharded(mesh, encode_matrix: np.ndarray, data,

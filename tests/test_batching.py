@@ -58,6 +58,44 @@ def test_batched_identity_same_pattern():
     asyncio.run(main())
 
 
+def test_batcher_group_runs_outside_the_request_context(monkeypatch):
+    """A group serves many requests, so its drain runs in a fresh
+    context: the submitting request's trace does not reach the group's
+    work.  The drain is scheduled through ``asyncio.create_task`` as
+    Python 3.10 has it (no ``context=``)."""
+    from chunky_bits_tpu.obs import tracing
+
+    d, p, size = 4, 2, 512
+    rows = _make_parts(1, d, p, size)[0]
+    seen = []
+    create_task = asyncio.create_task
+
+    def create_task_310(coro, *, name=None):
+        return create_task(coro, name=name)
+
+    monkeypatch.setattr(asyncio, "create_task", create_task_310)
+
+    class Probe(ReconstructBatcher):
+        def _run_group(self, key, requests):
+            seen.append(tracing.current())
+            return super()._run_group(key, requests)
+
+    async def main():
+        trace, token = tracing.start("req-1", keep=False)
+        try:
+            punched = list(rows)
+            punched[1] = None
+            got = await Probe(backend="numpy").reconstruct(d, p, punched)
+            assert tracing.current() is trace
+        finally:
+            tracing.finish(trace, token, duration=0.0, slow_s=1.0, meta={})
+        for i in range(d + p):
+            assert np.array_equal(got[i], rows[i])
+
+    asyncio.run(main())
+    assert seen == [None]
+
+
 def test_batched_mixed_patterns_and_sizes():
     d, p = 3, 2
     parts_a = _make_parts(3, d, p, 256, seed=1)
