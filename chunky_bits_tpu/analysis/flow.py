@@ -67,7 +67,7 @@ DURABILITY_ROOTS = (
     ("file/slab.py", "SlabStore.append"),
     ("file/slab.py", "SlabStore.mark_dead"),
     ("file/slab.py", "SlabStore.compact"),
-    ("file/location.py", "_publish_atomically"),
+    ("file/location.py", "_publish_sync"),
     ("cluster/metadata.py", "write"),
     ("cluster/repair.py", "repair_part"),
     ("cluster/scrub.py", "_rewrite_replicas"),
@@ -294,7 +294,7 @@ class CancelSafetyRule(Rule):
         finished write and its ``replace`` an arriving cancellation
         strands the temp file and skips the publish; wrap the window
         in ``asyncio.shield`` or keep it await-free (the
-        ``_publish_atomically`` shape).
+        ``_publish_sync`` shape: the whole window in one worker call).
 
     Justified sites record why with ``# lint: cancel-safety-ok
     <reason>``.
@@ -455,7 +455,7 @@ class CancelSafetyRule(Rule):
                        f"in async def {fn.name}(): a cancellation "
                        "delivered here strands the temp file and "
                        "skips the publish — keep the window "
-                       "await-free (the _publish_atomically shape) or "
+                       "await-free (the _publish_sync shape) or "
                        "wrap it in asyncio.shield, else justify with "
                        "`# lint: cancel-safety-ok <reason>`")
 

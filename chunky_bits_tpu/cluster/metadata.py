@@ -213,7 +213,7 @@ class MetadataPath:
 
         def _write() -> None:
             # Atomic publication, like every other local write in this
-            # repo (file/location._publish_atomically): the reference
+            # repo (file/location._publish_sync): the reference
             # truncates in place (metadata.rs:120-130), which lets a
             # concurrent reader observe an empty/torn reference — a
             # live hazard now that the scrub daemon republishes

@@ -20,6 +20,7 @@ import errno
 import io
 import os
 import re
+import stat
 from dataclasses import dataclass, field, replace
 from typing import Optional
 from urllib.parse import quote, urlsplit, urlunsplit
@@ -233,11 +234,14 @@ def is_publish_temp(name: str) -> bool:
     return _PUBLISH_TEMP_RE.search(name) is not None
 
 
-async def _publish_atomically(target: str, write_body) -> int:
-    """Local write published atomically where possible; the single
-    implementation of the publish protocol for both the whole-buffer and
-    streaming local write paths (``write_body(path) -> int`` lands the
-    bytes at the given path).
+def _publish_sync(target: str, data, ignore_existing: bool) -> str:
+    """Publish one whole-buffer local chunk, start to end, in the
+    calling thread: the single implementation of the local publish
+    protocol, run by ``Location.write`` as ONE worker-thread call (no
+    filesystem syscall on the event loop, one loop round trip per
+    chunk).  Returns the outcome, counted in ``cb_chunk_publish_total``:
+    ``skipped`` (``ignore_existing`` and the target exists), ``direct``
+    or ``renamed``.
 
     Regular-file targets are written to a sibling temp file and
     os.replace()d in, so a concurrent reader (including page-cache views
@@ -266,57 +270,84 @@ async def _publish_atomically(target: str, write_body) -> int:
     detach — correct for content-addressed chunks, where an in-place
     rewrite would mutate every linked path.
 
-    The pre-check rides a thread hop (CB201: on a network filesystem
-    its stat/exists syscalls are round trips, and this runs per chunk
-    on the gateway PUT path; the temp file does not exist yet, so the
-    hop opens no cleanup race).  The chmod+replace swap and the
-    error-path temp reaping deliberately stay sync: a suspension point
-    between the completed write and the rename would let a cancellation
-    interleave the reap with an in-flight swap (unlink-vs-replace race,
-    or a publish the caller observed as cancelled), and both are
-    bounded local metadata syscalls on a just-created staging file."""
-    direct, mode = await asyncio.to_thread(_publish_precheck, target)
-    if direct:
-        return await write_body(target)
-    tmp = publish_temp_name(target)
+    Nothing here awaits, so a cancellation cannot land between the
+    finished temp write and the rename.  The caller's cancellation
+    reaches it at once, and the worker cannot be interrupted: a thread
+    the executor has started runs this to its end, publishing or
+    reaping its own temp, and a call cancelled before its thread starts
+    never runs.  A chunk published for a request that was cancelled is
+    an orphan for ``find-unused-hashes``, like the shards of any failed
+    write."""
+    with obs_tracing.span("dest.publish", bytes=len(data)):
+        exists, direct, mode = _inspect_target(target)
+        if exists and ignore_existing:
+            outcome = "skipped"
+        elif direct:
+            _write_file(target, data)
+            outcome = "direct"
+        else:
+            tmp = publish_temp_name(target)
+            try:
+                _write_file(tmp, data)
+            except OSError as err:
+                if _reap_publish_temp(tmp) \
+                        or err.errno not in _TEMP_REFUSED:
+                    raise
+                _write_file(target, data)
+                outcome = "direct"
+            except BaseException:
+                _reap_publish_temp(tmp)
+                raise
+            else:
+                _swap_in(tmp, target, mode)
+                outcome = "renamed"
+    obs_metrics.record_chunk_publish(outcome)
+    return outcome
+
+
+def _inspect_target(target: str) -> tuple[bool, bool, Optional[int]]:
+    """(exists?, write-direct?, preserved mode) of a publication target
+    from one ``lstat`` — plus a ``stat`` only for a symlink, whose
+    existence is its referent's.  The answers ``os.path.exists`` /
+    ``islink`` / ``isfile`` / ``stat`` would give: a symlink or a
+    special file is written directly, a regular file's permission bits
+    carry over to its replacement."""
     try:
-        total = await write_body(tmp)
+        st = os.lstat(target)
+    except OSError:
+        return False, False, None
+    if stat.S_ISLNK(st.st_mode):
+        return os.path.exists(target), True, None
+    if not stat.S_ISREG(st.st_mode):
+        return True, True, None
+    return True, False, st.st_mode & 0o7777
+
+
+def _write_file(path: str, data) -> None:
+    with _fsio.open(path, "wb") as f:
+        f.write(data)
+        f.flush()
+
+
+#: errnos of a directory that refuses the temp file, where an in-place
+#: write may still be allowed
+_TEMP_REFUSED = (errno.EACCES, errno.EPERM, errno.EROFS)
+
+
+def _swap_in(tmp: str, target: str, mode: Optional[int]) -> None:
+    """Rename a finished temp over its target (carrying ``mode`` over);
+    on any failure the temp is reaped before the error propagates."""
+    try:
         if mode is not None:
-            # lint: async-blocking-ok bounded local chmod on the
-            # staging file; sync keeps publication atomic under
-            # cancellation (see docstring)
             os.chmod(tmp, mode)
-        # lint: async-blocking-ok bounded local rename; a suspension
-        # here would let a cancellation race the reap against the
-        # in-flight swap (see docstring)
         _fsio.replace(tmp, target)
-        return total
-    except OSError as err:
-        created = _reap_publish_temp(tmp)
-        if not created and err.errno in (errno.EACCES, errno.EPERM,
-                                         errno.EROFS):
-            return await write_body(target)
-        raise
     except BaseException:
         _reap_publish_temp(tmp)
         raise
 
 
-def _publish_precheck(target: str) -> tuple[bool, Optional[int]]:
-    """(write-direct?, preserved mode) for one publication — the sync
-    half of _publish_atomically's target inspection, batched into a
-    single executor hop."""
-    if os.path.islink(target) or (
-            os.path.exists(target) and not os.path.isfile(target)):
-        return True, None
-    try:
-        return False, os.stat(target).st_mode & 0o7777
-    except OSError:
-        return False, None
-
-
 def _reap_publish_temp(tmp: str) -> bool:
-    """Remove a staging temp; True when it existed (i.e. write_body got
+    """Remove a staging temp; True when it existed (i.e. the write got
     far enough to create it — the EACCES-fallback discriminator)."""
     created = os.path.exists(tmp)
     try:
@@ -326,20 +357,27 @@ def _reap_publish_temp(tmp: str) -> bool:
     return created
 
 
-async def _atomic_publish(target: str, data) -> None:
-    def _write(path: str) -> int:
-        with _fsio.open(path, "wb") as f:
-            f.write(data)
-            f.flush()
-        return len(data)
-
-    await _publish_atomically(
-        target, lambda path: asyncio.to_thread(_write, path))
-
-
 async def _atomic_publish_stream(reader, target: str) -> int:
-    return await _publish_atomically(
-        target, lambda path: aio.copy_reader_to_file(reader, path))
+    """The streaming local write: ``_publish_sync``'s protocol with the
+    body copied from ``reader`` on the loop; the target inspection, the
+    rename and the reap of a failed temp run in worker calls."""
+    _exists, direct, mode = await asyncio.to_thread(_inspect_target,
+                                                    target)
+    if direct:
+        return await aio.copy_reader_to_file(reader, target)
+    tmp = publish_temp_name(target)
+    try:
+        total = await aio.copy_reader_to_file(reader, tmp)
+    except OSError as err:
+        if await asyncio.to_thread(_reap_publish_temp, tmp) \
+                or err.errno not in _TEMP_REFUSED:
+            raise
+        return await aio.copy_reader_to_file(reader, target)
+    except BaseException:
+        await asyncio.to_thread(_reap_publish_temp, tmp)
+        raise
+    await asyncio.to_thread(_swap_in, tmp, target, mode)
+    return total
 
 
 class _HttpBodyReader:
@@ -908,7 +946,9 @@ class Location:
     async def write(self, data: bytes,
                     cx: Optional[LocationContext] = None) -> None:
         """Whole-buffer write with conflict policy; I/O-hooked and
-        spanned as ``dest.write`` (src/file/location.rs:185-244)."""
+        spanned as ``dest.write`` (src/file/location.rs:185-244).  A
+        local target is published by one worker call that also makes
+        the conflict check (``_publish_sync``)."""
         cx = cx or default_context()
         if self.range.is_specified():
             raise WriteToRangeError()
@@ -917,7 +957,8 @@ class Location:
             if cx.health is not None:
                 cx.health.begin(self)
             try:
-                if cx.on_conflict == IGNORE and await self.file_exists(cx):
+                if cx.on_conflict == IGNORE and not self.is_local() \
+                        and await self.file_exists(cx):
                     if cx.health is not None:
                         cx.health.finish(self, True,
                                          _clock.monotonic() - start)
@@ -938,7 +979,9 @@ class Location:
                     await node.write(name, data)
                 elif self.is_local():
                     try:
-                        await _atomic_publish(self.target, data)
+                        await asyncio.to_thread(
+                            _publish_sync, self.target, data,
+                            cx.on_conflict == IGNORE)
                     except OSError as err:
                         raise LocationError(str(err)) from err
                 else:

@@ -16,7 +16,7 @@ On-disk layout, rooted at a directory::
     <root>/.lock              flock target for cross-process appends
 
 Publication protocol (the slab analogue of the local plane's
-atomic-rename publication, ``location._publish_atomically``): chunk
+atomic-rename publication, ``location._publish_sync``): chunk
 bytes are appended to the active slab and flushed, THEN one complete
 journal line ``{"o": "p", "n": <name>, "s": <slab>, "f": <offset>,
 "l": <len>, "t": <unix>}`` is appended in a single write.  A chunk is
@@ -25,7 +25,7 @@ visible if and only if its journal line is written, so a crashed
 compaction) and possibly a torn final journal line (ignored by every
 reader — the journal parser only consumes whole lines, and the next
 append terminates the fragment).  Crash durability follows the repo's
-flush-only discipline (``_publish_atomically``: flush, no fsync per
+flush-only discipline (``_publish_sync``: flush, no fsync per
 publication): after a *power loss* the page cache may persist the
 journal line without the slab bytes it references, leaving a live
 extent of stale/zero bytes — the same class of silent loss flush-only

@@ -395,6 +395,18 @@ def record_io(op: str, ok: bool, nbytes: int, duration: float) -> None:
             labels=("op",))).labels(op=op).inc(max(nbytes, 0))
 
 
+def record_chunk_publish(outcome: str) -> None:
+    """One whole-buffer local chunk publication (``file/location.py``):
+    ``renamed`` into place from its temp, written ``direct`` (a symlink,
+    a special file, or a directory that refuses the temp), or
+    ``skipped`` because the chunk was already there."""
+    outcome = outcome if outcome in ("renamed", "direct", "skipped") \
+        else "other"
+    _event_family("chunk_publish", lambda reg: reg.counter(
+        "cb_chunk_publish_total", "local chunk publications by outcome",
+        labels=("outcome",))).labels(outcome=outcome).inc()
+
+
 def record_batch(op: str, parts: int = 0, dispatches: int = 0) -> None:
     """One coalesced group of a codec batcher (``ops/batching.py``): the
     parts it carried and the codec dispatches it issued, so parts per

@@ -47,6 +47,8 @@ The span catalog (names are stable: benchmark readers select by name):
                             bytes)
 ``meta.write``/``meta.read``  file-reference publish (with fsync) / load
 ``dest.write``              one chunk write at its location (bytes)
+``dest.publish``            a local chunk's publication in its worker
+                            thread (bytes)
 ``reader.fetch``            one chunk's verified fetch (bytes)
 ``reader.reconstruct``      a part's await on the decode batcher
 ``batching.<op>.queue``     batcher submit to group start

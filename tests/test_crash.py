@@ -30,6 +30,7 @@ import hashlib
 import os
 import subprocess
 import sys
+import threading
 import time
 
 import pytest
@@ -230,6 +231,42 @@ def test_crash_matrix_catches_a_dropped_dir_fsync(tmp_path, monkeypatch):
     assert failed, "neutered fsync_dir went undetected"
     assert any(v.mode == "powercut-meta" and "acknowledged" in
                " ".join(v.violations) for v in failed)
+
+
+def test_chunk_publish_is_recorded_whole_from_one_worker(tmp_path):
+    """A chunk publication runs as one worker call: the recorder sees
+    its whole protocol (temp open, write, flush, close, rename onto the
+    target), every op from one thread that is not the event loop's."""
+    from chunky_bits_tpu.file.location import Location, is_publish_temp
+
+    class ThreadNoting(fsio.RecordingFsProvider):
+        def __init__(self, root):
+            super().__init__(root)
+            self.threads = set()
+
+        def open(self, path, mode="r", **kwargs):
+            self.threads.add(threading.get_ident())
+            return super().open(path, mode, **kwargs)
+
+        def replace(self, src, dst):
+            self.threads.add(threading.get_ident())
+            super().replace(src, dst)
+
+    recorder = ThreadNoting(str(tmp_path))
+    fsio.install(recorder)
+
+    async def main():
+        await Location.parse(str(tmp_path / "chunk")).write(b"payload")
+        return threading.get_ident()
+
+    loop_thread = asyncio.run(main())
+    fsio.install(None)
+    assert [op.op for op in recorder.ops] == [
+        "open", "write", "flush", "close", "replace"]
+    assert is_publish_temp(recorder.ops[0].path)
+    assert recorder.ops[-1].path == "chunk"
+    assert len(recorder.threads) == 1
+    assert loop_thread not in recorder.threads
 
 
 # ---- scripted live faults (the FaultyFsProvider satellite pins) ----

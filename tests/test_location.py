@@ -3,7 +3,10 @@ FS + HTTP read, subfile write, streaming, conflict policy, ranges,
 parse/display."""
 
 import asyncio
+import errno
 import os
+import threading
+from concurrent.futures import ThreadPoolExecutor
 
 import pytest
 
@@ -19,8 +22,10 @@ from chunky_bits_tpu.file.location import (
     Location,
     LocationContext,
     Range,
+    is_publish_temp,
 )
-from chunky_bits_tpu.utils import aio
+from chunky_bits_tpu.obs import metrics as obs_metrics
+from chunky_bits_tpu.utils import aio, fsio
 from tests.http_node import FakeHttpNode
 
 
@@ -507,3 +512,230 @@ def test_atomic_write_edge_cases(tmp_path):
         assert [x for x in os.listdir(tmp_path) if ".tmp." in x] == []
 
     asyncio.run(main())
+
+
+# ---- local chunk publication: one worker call, off the loop ----
+
+
+class _ThreadRecordingFs(fsio.FsProvider):
+    """Passthrough seam that notes the thread of every call."""
+
+    def __init__(self):
+        self.calls = []
+
+    def open(self, path, mode="r", **kwargs):
+        self.calls.append(("open", threading.get_ident()))
+        return super().open(path, mode, **kwargs)
+
+    def replace(self, src, dst):
+        self.calls.append(("replace", threading.get_ident()))
+        super().replace(src, dst)
+
+    def unlink(self, path):
+        self.calls.append(("unlink", threading.get_ident()))
+        super().unlink(path)
+
+
+@pytest.fixture
+def seam():
+    """Installs a provider for the test and always restores the
+    passthrough."""
+    yield fsio.install
+    fsio.install(None)
+
+
+class _CountingExecutor(ThreadPoolExecutor):
+    def __init__(self):
+        super().__init__(max_workers=4)
+        self.submits = 0
+
+    def submit(self, *args, **kwargs):
+        self.submits += 1
+        return super().submit(*args, **kwargs)
+
+
+def _publish_counts():
+    samples = obs_metrics.get_registry().counter(
+        "cb_chunk_publish_total", labels=("outcome",))._samples()
+    return {s["labels"]["outcome"]: s["value"] for s in samples}
+
+
+@pytest.fixture
+def publish_counter(monkeypatch):
+    """Reads ``cb_chunk_publish_total`` by outcome from the registry the
+    test's records land in."""
+    monkeypatch.setattr(obs_metrics, "_EVENT_FAMILIES", {})
+    return _publish_counts
+
+
+def _temps(path):
+    return [f for f in os.listdir(path) if ".tmp." in f]
+
+
+@pytest.mark.parametrize("how", ["write", "write-ignore", "stream"])
+def test_local_publication_keeps_filesystem_calls_off_the_loop(
+        tmp_path, seam, how):
+    """No seam call of a local publication (temp open, rename, reap)
+    runs on the event loop's thread, the rename included."""
+    rec = _ThreadRecordingFs()
+    seam(rec)
+    target = tmp_path / "chunk"
+    target.write_bytes(b"old")
+
+    async def main():
+        loc = Location.parse(str(target))
+        if how == "stream":
+            await loc.write_from_reader(aio.BytesReader(b"new"))
+        else:
+            cx = LocationContext(
+                on_conflict=IGNORE if how == "write-ignore" else "overwrite")
+            await loc.write(b"new", cx)
+            await Location.parse(str(tmp_path / "fresh")).write(b"x", cx)
+        return threading.get_ident()
+
+    loop_thread = asyncio.run(main())
+    ops = [op for op, _ in rec.calls]
+    assert "open" in ops and "replace" in ops, ops
+    assert all(t != loop_thread for _, t in rec.calls), rec.calls
+    assert target.read_bytes() == (b"old" if how == "write-ignore"
+                                   else b"new")
+    assert _temps(tmp_path) == []
+
+
+@pytest.mark.parametrize("existing", [False, True])
+def test_local_write_is_one_executor_call(tmp_path, seam, existing):
+    """A local chunk write makes exactly one executor call; under IGNORE
+    an existing target costs that one call and writes nothing."""
+    rec = _ThreadRecordingFs()
+    seam(rec)
+    target = tmp_path / "chunk"
+    if existing:
+        target.write_bytes(b"there")
+    pool = _CountingExecutor()
+
+    async def main():
+        asyncio.get_running_loop().set_default_executor(pool)
+        await Location.parse(str(target)).write(
+            b"payload", LocationContext(on_conflict=IGNORE))
+
+    asyncio.run(main())
+    assert pool.submits == 1
+    if existing:
+        assert rec.calls == []
+        assert target.read_bytes() == b"there"
+    else:
+        assert [op for op, _ in rec.calls] == ["open", "replace"]
+        assert target.read_bytes() == b"payload"
+
+
+class _BlockingWriteFs(fsio.FsProvider):
+    """Holds the temp write until released; optionally fails it."""
+
+    def __init__(self, fail):
+        self.fail = fail
+        self.in_write = threading.Event()
+        self.release = threading.Event()
+
+    def open(self, path, mode="r", **kwargs):
+        real = super().open(path, mode, **kwargs)
+        if "w" not in mode:
+            return real
+        provider = self
+
+        class _File:
+            def write(self, data):
+                provider.in_write.set()
+                provider.release.wait(10)
+                if provider.fail:
+                    raise OSError(errno.EIO, "injected")
+                return real.write(data)
+
+            def flush(self):
+                real.flush()
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                real.close()
+
+        return _File()
+
+
+@pytest.mark.parametrize("fail", [False, True])
+def test_cancelled_publication_completes_or_reaps_its_temp(
+        tmp_path, seam, fail):
+    """Cancelling the writer while its worker is inside the temp write:
+    the CancelledError reaches the caller at once, and once the worker
+    is done the target is whole (or absent, when the write failed) with
+    no temp file left."""
+    fs = _BlockingWriteFs(fail)
+    seam(fs)
+    target = tmp_path / "chunk"
+    data = os.urandom(4096)
+
+    async def main():
+        task = asyncio.create_task(Location.parse(str(target)).write(data))
+        assert await asyncio.to_thread(fs.in_write.wait, 10)
+        task.cancel()
+        with pytest.raises(asyncio.CancelledError):
+            await task
+        assert not fs.release.is_set()  # the worker is still writing
+        fs.release.set()
+
+    # asyncio.run returns once the default executor's threads are done
+    asyncio.run(main())
+    if fail:
+        assert not target.exists()
+    else:
+        assert target.read_bytes() == data
+    assert _temps(tmp_path) == []
+
+
+class _RefuseTempsFs(fsio.FsProvider):
+    """A directory that refuses new files but not writes in place."""
+
+    def open(self, path, mode="r", **kwargs):
+        if is_publish_temp(path):
+            raise PermissionError(errno.EACCES, "refused", path)
+        return super().open(path, mode, **kwargs)
+
+
+def test_publication_outcomes_are_counted(tmp_path, seam, publish_counter):
+    """``cb_chunk_publish_total`` counts each outcome: a fresh chunk is
+    renamed into place, an existing one skipped under IGNORE, a symlink,
+    a special file and a directory refusing the temp are written
+    directly — each as before, with no temp left behind."""
+    ignore = LocationContext(on_conflict=IGNORE)
+    real = tmp_path / "real.bin"
+    real.write_bytes(b"old")
+    link = tmp_path / "link.bin"
+    link.symlink_to(real)
+    dangling = tmp_path / "dangling.bin"
+    dangling.symlink_to(tmp_path / "absent.bin")
+    refused = tmp_path / "refused.bin"
+    refused.write_bytes(b"old")
+
+    async def main():
+        await Location.parse(str(tmp_path / "a")).write(b"a")
+        await Location.parse(str(tmp_path / "a")).write(b"b", ignore)
+        await Location.parse(str(link)).write(b"through")
+        # a dangling link does not exist: IGNORE writes through it
+        await Location.parse(str(dangling)).write(b"made", ignore)
+        await Location.parse(os.devnull).write(b"x")
+        seam(_RefuseTempsFs())
+        await Location.parse(str(refused)).write(b"in place")
+        seam(None)
+
+    before = publish_counter()
+    asyncio.run(main())
+    after = publish_counter()
+    delta = {k: after.get(k, 0) - before.get(k, 0)
+             for k in ("renamed", "skipped", "direct")}
+    assert delta == {"renamed": 1, "skipped": 1, "direct": 4}
+    assert (tmp_path / "a").read_bytes() == b"a"
+    assert link.is_symlink() and real.read_bytes() == b"through"
+    assert dangling.is_symlink()
+    assert (tmp_path / "absent.bin").read_bytes() == b"made"
+    assert refused.read_bytes() == b"in place"
+    assert _temps(tmp_path) == []

@@ -22,7 +22,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 #: spans of one request, each carrying its req
 PUT_SPANS = {"gateway.put", "gateway.put.admit", "writer.receive",
              "writer.encode", "batching.encode.queue", "host.stage",
-             "host.stage.queue", "dest.write", "meta.write"}
+             "host.stage.queue", "dest.write", "dest.publish",
+             "meta.write"}
 GET_SPANS = {"gateway.get", "meta.read", "reader.fetch", "host.verify",
              "reader.reconstruct", "batching.reconstruct.queue",
              "gateway.get.send"}
@@ -92,6 +93,23 @@ def test_put_and_degraded_get_emit_the_catalog(trace):
               if n == "dest.write" and s.get("req") == put_req]
     # 3 parts of d+p = 5 chunks each
     assert len(writes) == 15
+
+
+def test_each_chunk_publication_lies_inside_its_write(trace):
+    """``dest.publish`` is opened in the worker thread that publishes a
+    chunk, off the event loop's line, and carries its request's ``req``
+    there: one per chunk write, each inside a ``dest.write`` of its
+    request."""
+    _, events = trace
+    writes = [(lo, hi, s) for lo, hi, n, s in events if n == "dest.write"]
+    publishes = [(lo, hi, s) for lo, hi, n, s in events
+                 if n == "dest.publish"]
+    assert len(publishes) == len(writes) >= 15
+    for lo, hi, stats in publishes:
+        inside = [w for w in writes if w[0] <= lo and hi <= w[1]
+                  and w[2].get("req") == stats.get("req")]
+        assert inside, (lo, hi, stats)
+        assert all(w[2]["line"] != stats["line"] for w in inside)
 
 
 def test_child_spans_lie_inside_their_request(trace):
