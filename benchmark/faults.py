@@ -17,6 +17,9 @@ reference can see it:
 * ``control``: the guarantee a PUT's 200 states is broken: one parity
   chunk file of every part is deleted before the PUT is acknowledged.
   On a GET cell the control is ``altered``: the exact-bytes guarantee.
+  On a repair cell, the guarantee a repair's success states: each
+  rebuilt chunk file is deleted once its write returns, before the job
+  is recorded.
 """
 
 from __future__ import annotations
@@ -119,6 +122,27 @@ def _control_put():
         FilePart.write_with_coder = staticmethod(orig)
 
 
+@contextlib.contextmanager
+def _control_resilver():
+    import os
+
+    from chunky_bits_tpu.cluster.destination import ClusterWriter
+
+    orig = ClusterWriter.write_shard
+
+    async def write_shard(self, hash_, data):
+        locations = await orig(self, hash_, data)
+        for loc in locations:
+            os.remove(loc.target)
+        return locations
+
+    ClusterWriter.write_shard = write_shard
+    try:
+        yield
+    finally:
+        ClusterWriter.write_shard = orig
+
+
 FAULTS = ("altered", "unchanged", "half_batch", "no_exchange")
 
 
@@ -127,6 +151,8 @@ def plant(name: str, traffic: dict):
     if name == "control":
         ops = traffic["op"]
         ops = {o for o, _ in ops} if isinstance(ops, list) else {ops}
+        if "resilver" in ops:
+            return _control_resilver()
         return _control_put() if "put" in ops else _codec("altered")
     if name == "no_exchange":
         return _no_exchange()

@@ -29,8 +29,17 @@ class Payload:
 
     def body(self, index: int, size: int) -> np.ndarray:
         """The bytes of object ``index`` (a fresh array)."""
+        return self.body_into(index, size, np.empty(size, dtype=np.uint8))
+
+    def body_into(self, index: int, size: int, out: np.ndarray
+                  ) -> np.ndarray:
+        """The bytes of object ``index``, written into the first ``size``
+        bytes of ``out`` and returned as that slice: a buffer that is
+        reused keeps its pages, where a fresh one of some hundreds of MiB
+        pays a page fault every 4 KiB."""
         lo = self.offset(index, size)
-        buf = self.pool[lo:lo + size].copy()
+        buf = out[:size]
+        buf[:] = self.pool[lo:lo + size]
         words = buf[:size - size % 8].view(np.uint64)
         stride = STAMP_EVERY // 8
         n = -(-len(words) // stride)

@@ -1,6 +1,7 @@
 #!/usr/bin/env python3
 """Run one benchmark cell: the HTTP gateway on the chip, driven from a
-child process, then the reference check.
+child process, or the repair loop in this process; then the reference
+check.
 
     python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s> \
         --trace <0|1>
@@ -12,7 +13,11 @@ on its event loop and starts ``benchmark/loadgen.py``, which never
 imports JAX.  The window opens after the mix's warm-up seconds and lasts
 ``--seconds``; requests still in flight when it closes are drained
 outside it.  Then the server stops, the device's peak memory is read,
-and what the window produced is compared with ``benchmark/reference.py``.
+and what the window produced is compared with ``benchmark/reference.py``
+(or the reference the configuration names).  A repair mix (``op:
+resilver``) serves nothing: its ``clients`` workers run on this
+process's event loop, each replaying one destination's loss on an
+object and then the CLI's ``resilver`` on it.
 
 The last stdout line is one JSON object: ``correct``, ``attempted``,
 ``failed``, ``metrics`` (the cell's end-to-end metrics with ``--trace
@@ -26,12 +31,14 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import itertools
 import json
 import math
 import os
 import shutil
 import sys
 import time
+import traceback
 from dataclasses import dataclass
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
@@ -43,7 +50,7 @@ import spec as specmod  # noqa: E402
 import store  # noqa: E402
 
 GIB = float(1 << 30)
-OK = {"put": (200,), "get": (200,), "head": (200,)}
+OK = {"put": (200,), "get": (200,), "head": (200,), "resilver": (200,)}
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
@@ -110,6 +117,42 @@ def overlap(start: float, end: float, t0: float, t1: float) -> float:
     return max(0.0, min(end, t1) - max(start, t0)) / (end - start)
 
 
+def gib_s(records: list, op: str, t0: float, t1: float) -> float:
+    """GiB/s of the bytes of ``op``'s successful records over the window
+    ``[t0, t1]``, each record weighted by the share of its duration
+    inside it."""
+    done = sum(r[4] * overlap(r[1], r[2], t0, t1) for r in records
+               if r[0] == op and r[3] in OK[op])
+    return done / GIB / (t1 - t0)
+
+
+def _batch_counters(op: str) -> dict:
+    """The process registry's ``cb_batch_parts_total{op}`` and
+    ``cb_batch_dispatches_total{op}`` (``obs/metrics.py``), 0 before
+    their first event."""
+    from chunky_bits_tpu.obs import metrics
+
+    out = {"cb_batch_parts_total": 0.0, "cb_batch_dispatches_total": 0.0}
+    for fam in metrics.get_registry().snapshot()["families"]:
+        if fam["name"] in out:
+            out[fam["name"]] = sum(s["value"] for s in fam["samples"]
+                                   if s["labels"].get("op") == op)
+    return out
+
+
+def window_counters(before: dict, after: dict, counter, t0: float,
+                    t1: float) -> dict:
+    """The counters' deltas over the window, the host's thread count and
+    the compiles inside it."""
+    counters = {k: after[k] - before[k] for k in after}
+    counters["host_threads"] = after["host_threads"]
+    compiled = counter.between(t0, t1)
+    counters["compiles"] = len(compiled)
+    if compiled:
+        print(f"run: compiled in the window: {compiled}", file=sys.stderr)
+    return counters
+
+
 def percentile(values: list[float], q: float) -> float:
     """Linear interpolation between order statistics (numpy's default);
     an infinite value (a failed request) stays beyond any limit."""
@@ -149,12 +192,19 @@ class Session:
         enc = cluster._encode_batchers.get(self.loop)
         rec = cluster._reconstruct_batchers.get(self.loop)
         stats = cluster.host_pipeline().stats()
+        # every decode batcher's, the per-file ones of a repair too
+        batches = _batch_counters("reconstruct")
         return {
             "encode_dispatches": enc.dispatches if enc else 0,
             "reconstruct_dispatches": rec.dispatches if rec else 0,
             "hash_busy_s": sum(s.busy_s for s in stats.stages
                                if s.stage == "hash"),
+            "verify_busy_s": sum(s.busy_s for s in stats.stages
+                                 if s.stage == "verify"),
             "host_threads": stats.threads,
+            "reconstruct_batch_parts": batches["cb_batch_parts_total"],
+            "reconstruct_batch_dispatches":
+                batches["cb_batch_dispatches_total"],
         }
 
     async def run(self) -> dict:
@@ -178,6 +228,8 @@ class Session:
         if self.fault is not None:
             self.fault.__enter__()
         try:
+            if "resilver" in store.ops_of(traffic):
+                return await self._repair(cluster, names, counter)
             return await self._serve(cluster, names, counter)
         finally:
             if self.fault is not None:
@@ -238,14 +290,73 @@ class Session:
                 await child.wait()
             server.cancel()
             await asyncio.gather(server, return_exceptions=True)
-        counters = {k: after[k] - before[k] for k in after}
-        counters["host_threads"] = after["host_threads"]
-        compiled = counter.between(t0, t1)
-        counters["compiles"] = len(compiled)
-        if compiled:
-            print(f"run: compiled in the window: {compiled}",
-                  file=sys.stderr)
+        counters = window_counters(before, after, counter, t0, t1)
         return self._result(records, t0, t1, setup_s, counters, hello,
+                            names, trace_dir)
+
+    async def _repair(self, cluster, names: list, counter) -> dict:
+        """The repair loop: worker ``w`` of the mix's ``clients`` takes
+        the objects ``w``, ``w + clients``, ... in turn, so no object is
+        in two jobs at once.  A job replays the loss on its object
+        (``store.Loss``, not timed), then runs the CLI's ``resilver`` on
+        it (timed) and is recorded as ``["resilver", start, end, status,
+        rebuilt chunk bytes, name, None]``.  Workers stop taking jobs at
+        the window's close; the jobs in flight then are drained."""
+        from chunky_bits_tpu.cli.cluster_location import ClusterLocation
+        from chunky_bits_tpu.cli.config import Config
+
+        args, traffic = self.args, self.cell.traffic
+        loss = store.Loss(self.work, names, traffic["lost_destination"])
+        config = Config(clusters={"bench": {"cluster": cluster}})
+        clients = int(traffic["clients"])
+        records: list = []
+        t0 = time.monotonic() + float(traffic.get("warmup_seconds", 2))
+        t1 = t0 + args.seconds
+
+        async def worker(mine: list) -> None:
+            for i in itertools.count():
+                if not mine or time.monotonic() >= t1:
+                    return
+                name = mine[i % len(mine)]
+                lost = await asyncio.to_thread(loss.apply, name)
+                start = time.monotonic()
+                try:
+                    report = await ClusterLocation(
+                        "cluster", cluster="bench", path=name
+                    ).resilver(config)
+                # a job that raises is recorded as failed, and the
+                # others go on
+                except Exception:
+                    traceback.print_exc()
+                    status, nbytes = -1, 0
+                else:
+                    status, nbytes = store.repair_outcome(report, lost)
+                records.append(["resilver", start, time.monotonic(),
+                                status, nbytes, name, None])
+
+        tasks = [asyncio.create_task(worker(names[w::clients]))
+                 for w in range(clients)]
+        trace_dir = os.path.join(self.work, "trace")
+        try:
+            await asyncio.sleep(max(0.0, t0 - time.monotonic()))
+            setup_s = time.perf_counter() - self.t_start
+            if args.trace:
+                import jax
+
+                jax.profiler.start_trace(
+                    trace_dir, profiler_options=_trace_options())
+            before = self.snapshot(cluster)
+            await asyncio.sleep(max(0.0, t1 - time.monotonic()))
+            after = self.snapshot(cluster)
+            if args.trace:
+                await asyncio.to_thread(jax.profiler.stop_trace)
+            await asyncio.wait_for(asyncio.gather(*tasks), 300)
+        finally:
+            for task in tasks:
+                task.cancel()
+            await asyncio.gather(*tasks, return_exceptions=True)
+        counters = window_counters(before, after, counter, t0, t1)
+        return self._result(records, t0, t1, setup_s, counters, None,
                             names, trace_dir)
 
     # ---- metrics and the check ----
@@ -258,8 +369,6 @@ class Session:
         in_window = [r for r in records if t0 <= r[1] < t1]
         failed = [r for r in in_window if r[3] not in OK[r[0]]]
         acked = [r for r in records if r[3] in OK[r[0]]]
-        put_bytes = sum(r[4] * overlap(r[1], r[2], t0, t1)
-                        for r in acked if r[0] == "put")
         parts = {op: sum(parts_of(r[4], profile) * overlap(r[1], r[2],
                                                           t0, t1)
                          for r in acked if r[0] == op)
@@ -271,7 +380,8 @@ class Session:
                  for r in in_window if r[0] == op], 0.95)
 
         values = {"setup_s": setup_s,
-                  "put_gib_s": put_bytes / GIB / window,
+                  "put_gib_s": gib_s(records, "put", t0, t1),
+                  "repair_gib_s": gib_s(records, "resilver", t0, t1),
                   "put_p95_ms": p95("put"), "get_p95_ms": p95("get")}
         device = device_info(self.devices, cell.chips)
         result = {"correct": None, "attempted": len(in_window),
@@ -303,11 +413,11 @@ class Session:
         else:
             metrics = {m.name: {"value": values[m.name], "unit": m.unit}
                        for m in cell.end_to_end}
+        gen = ("" if hello is None else f", load generator builds "
+               f"{hello['gen_bytes_per_s'] / GIB} GiB/s")
         print(f"run: window {window} s, {len(records)} requests "
               f"({len(in_window)} started in the window, {len(failed)} "
-              f"failed), load generator builds "
-              f"{hello['gen_bytes_per_s'] / GIB} GiB/s, counters "
-              f"{counters}", file=sys.stderr)
+              f"failed){gen}, counters {counters}", file=sys.stderr)
         print(f"run: store holds {store.bytes_on_disk(self.work)} bytes",
               file=sys.stderr)
         checks = check.compare(self.work, cell, args.seed, records, names,

@@ -1,5 +1,6 @@
-"""The cell's store: its cluster description, the objects a GET mix
-reads, and the warm-up of every program the mix dispatches.
+"""The cell's store: its cluster description, the objects a GET or a
+repair mix reads, the loss a repair mix replays, and the warm-up of
+every program the mix dispatches.
 
 Destinations are directories under ``<checkout>/.bench_work/store`` on
 the machine's disk, one per shard slot, with a JSON metadata directory
@@ -16,6 +17,10 @@ from payload import Payload
 
 
 def cluster_obj(work: str, config: dict, backend: str) -> dict:
+    """The cluster of ``config`` with its codec on ``backend``: the
+    configuration's optional ``tunables`` object, with ``backend`` set
+    by the caller (the configuration's own, or a mix's
+    ``store_backend`` for set-up)."""
     root = os.path.join(work, "store")
     disks = [os.path.join(root, f"disk{i:02d}")
              for i in range(config["destinations"])]
@@ -24,7 +29,7 @@ def cluster_obj(work: str, config: dict, backend: str) -> dict:
         os.makedirs(d, exist_ok=True)
     return {"destinations": [{"location": d} for d in disks],
             "metadata": {"type": "path", "format": "json", "path": meta},
-            "tunables": {"backend": backend},
+            "tunables": dict(config.get("tunables", {}), backend=backend),
             "profiles": {"default": dict(config["profile"])}}
 
 
@@ -83,6 +88,57 @@ async def build(work: str, config: dict, traffic: dict, seed: int
     return [store_name(i) for i in range(count)]
 
 
+class Loss:
+    """One destination lost, replayed on an object before each repair of
+    it: its file reference is put back as set-up wrote it, and every
+    chunk file that reference records under the lost directory is
+    unlinked.  Each part then misses the one slot that placement gave
+    that destination, so the parts of one object miss different slots.
+    """
+
+    def __init__(self, work: str, names: list[str],
+                 destination: str) -> None:
+        self.work = work
+        lost = os.path.join(work, "store", destination) + os.sep
+        #: name -> the file reference's bytes as set-up wrote them
+        self.refs: dict[str, bytes] = {}
+        #: name -> the chunk files it records under the lost directory
+        self.files: dict[str, list[str]] = {}
+        for name in names:
+            with open(meta_path(work, name), "rb") as f:
+                raw = f.read()
+            ref = json.loads(raw)
+            self.refs[name] = raw
+            self.files[name] = sorted({
+                loc for part in ref["parts"]
+                for chunk in part["data"] + part["parity"]
+                for loc in chunk["locations"] if loc.startswith(lost)})
+
+    def apply(self, name: str) -> int:
+        """Lose ``name``'s chunks on the destination; returns how many
+        chunks it lost."""
+        with open(meta_path(self.work, name), "wb") as f:
+            f.write(self.refs[name])
+        for path in self.files[name]:
+            try:
+                os.remove(path)
+            except FileNotFoundError:
+                pass
+        return len(self.files[name])
+
+
+def repair_outcome(report, lost: int) -> tuple[int, int]:
+    """``(status, rebuilt chunk bytes)`` of a resilver report: 200 only
+    when no part reports an error and all ``lost`` chunks were
+    written."""
+    parts = report.part_reports
+    written = sum(len(r.successful_writes()) for r in parts)
+    nbytes = sum(r.file_part.chunksize * len(r.successful_writes())
+                 for r in parts)
+    ok = written == lost and not report.failed_writes()
+    return (200 if ok else 500), nbytes
+
+
 def bytes_on_disk(work: str) -> int:
     total = 0
     for dirpath, _dirs, files in os.walk(os.path.join(work, "store")):
@@ -121,8 +177,10 @@ def warm(cluster, config: dict, traffic: dict) -> int:
     """Run every codec program the mix can dispatch once: encode for PUTs,
     the decode of the lost slots for GETs, for each shard length and each
     batch size from 1 to the mix's ``warm_batches``, or to the parts of
-    that length all clients can have in flight if that is fewer.
-    Returns the calls made."""
+    that length all clients can have in flight if that is fewer; for a
+    repair, the decode of one lost slot for each shard length and each
+    batch size up to the parts of that length one object's repair has in
+    flight.  Returns the calls made."""
     import numpy as np
 
     from chunky_bits_tpu.ops.backend import get_coder
@@ -134,6 +192,8 @@ def warm(cluster, config: dict, traffic: dict) -> int:
     clients = int(traffic["clients"])
     calls = []
     ops = ops_of(traffic)
+    store_sizes = (_sizes(traffic, "store_object_bytes")
+                   if "store_object_bytes" in traffic else [])
     if "put" in ops:
         for s, n in shard_lengths(
                 config, _sizes(traffic, "object_bytes")).items():
@@ -142,11 +202,21 @@ def warm(cluster, config: dict, traffic: dict) -> int:
     if "get" in ops and lost:
         present = [i for i in range(d + p) if i not in lost]
         wanted = [i for i in lost if i < d]
-        for s, n in shard_lengths(
-                config, _sizes(traffic, "store_object_bytes")).items():
+        for s, n in shard_lengths(config, store_sizes).items():
             calls.append((s, min(top, n * clients),
                           lambda x: coder.reconstruct_batch_picked(
                               x, present, wanted)))
+    if "resilver" in ops:
+        from chunky_bits_tpu.file.file_reference import RESILVER_CONCURRENCY
+
+        # one destination lost: every part misses one slot, data or
+        # parity (the shape is the same whichever).  Each file's repair
+        # has its own decode batcher, so a batch holds at most the parts
+        # of one object that are in flight at once.
+        for s, n in shard_lengths(config, store_sizes).items():
+            calls.append((s, min(n, RESILVER_CONCURRENCY),
+                          lambda x: coder.reconstruct_batch_picked(
+                              x, list(range(1, d + p)), [0])))
     # the single-chip backend takes its input from an uncommitted
     # jax.device_put, and jit caches committed and uncommitted inputs
     # apart: warm with uncommitted zeros made on the device (no copy in)

@@ -34,6 +34,10 @@ TINY_TRAFFIC = {
                          "warmup_seconds": 0.5, "warm_batches": 2},
     "put-320m": {"object_bytes": 2 * 20 * 4096, "clients": 1,
                  "warmup_seconds": 0.5, "warm_batches": 2},
+    "resilver-1disk": {"store_objects": 6,
+                       "store_object_bytes": 3 * 10 * 4096 + 5 * 4096 + 123,
+                       "clients": 3, "warmup_seconds": 0.5,
+                       "check_objects": 2},
 }
 
 

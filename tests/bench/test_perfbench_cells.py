@@ -19,7 +19,8 @@ import run  # noqa: E402
 import trace_reduce  # noqa: E402
 
 CELLS = ["rs10-4-1m.put-256m", "d8p3-512k.put-4m",
-         "rs10-4-1m.get-degraded-10m", "d20p6-4m.put-320m"]
+         "rs10-4-1m.get-degraded-10m", "d20p6-4m.put-320m",
+         "rs10-4-1m.resilver-1disk"]
 SEED = 2**33 + 11
 
 
@@ -51,9 +52,10 @@ def test_cell_on_cpu(tmp_path, capsys, cell):
     out = run_tiny(tmp_path, capsys, cell)
     assert out["correct"] is True, out["checks"]
     assert out["attempted"] > 0 and out["failed"] == 0
-    want = {"setup_s"} | ({"get_p95_ms"} if "get" in cell else
-                          {"put_gib_s"} | ({"put_p95_ms"}
-                                           if "put-4m" in cell else set()))
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    want = {m["name"] for m in bench["end_to_end"]
+            if cell in m.get("workloads", [cell])}
     assert set(out["metrics"]) == want
     assert all(m["value"] > 0 for m in out["metrics"].values())
     assert out["device"]["platform"] == "cpu"

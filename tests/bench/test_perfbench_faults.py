@@ -24,6 +24,8 @@ CASES = [
         ("rs10-4-1m.get-degraded-10m", ["control", "unchanged",
                                         "half_batch"]),
         ("d20p6-4m.put-320m", ["control", "altered", "no_exchange"]),
+        ("rs10-4-1m.resilver-1disk", ["control", "altered", "unchanged",
+                                      "half_batch"]),
     ]
     for fault in faults
 ]

@@ -71,3 +71,16 @@ def test_payload_is_seeded_and_every_block_stamped():
     assert not bx & by
     stamps = x.view(np.uint64)[::512]
     assert list(stamps[:3]) == [(3 << 32) | i for i in range(3)]
+
+
+@pytest.mark.parametrize("size", [1 << 20, (1 << 20) - 13, 4096 * 3 + 5])
+def test_body_built_in_a_reused_buffer_is_the_objects_bytes(size):
+    """The load generator builds each body in its client's buffer; the
+    check rebuilds it fresh: both give the same bytes, and nothing of a
+    longer earlier body is left in the slice."""
+    p = payload.Payload(2**31 + 9, 1 << 20)
+    out = np.full(1 << 20, 0xAB, dtype=np.uint8)
+    p.body_into(5, 1 << 20, out)
+    got = p.body_into(6, size, out)
+    assert got.base is out or got.base is out.base
+    assert np.array_equal(got, p.body(6, size))
