@@ -12,12 +12,14 @@ whose bytes hash to it.
 GET: the load generator hashed every body it received; each must equal
 the SHA-256 of the object as the seed makes it.
 
-Repair: every job succeeded; every chunk of every part of every object
-has a location whose file hashes to the chunk's recorded SHA-256, so
-each object's last repair made its redundancy whole again; and for a
-seeded sample of the mix's ``check_objects`` objects the recorded
-layout and addresses equal the reference's, with every location holding
-those bytes.
+Repair (``resilver`` or ``scrub``): every job succeeded; every chunk of
+every part of every object has a location whose file hashes to the
+chunk's recorded SHA-256, so each object's last repair made its
+redundancy whole again; for a seeded sample of the mix's
+``check_objects`` objects the recorded layout and addresses equal the
+reference's, with every location holding those bytes; and the repair
+went through the path the mix names: a decode dispatch or more for
+``resilver``, a repair plan or more run in place for ``scrub``.
 
 The reference is ``reference.py`` unless the configuration names
 another module of the benchmark's under ``"reference"``, with the same
@@ -151,7 +153,7 @@ def compare(work: str, cell, seed: int, records: list, names: list,
             n = counters["reconstruct_dispatches"]
             checks["decode_dispatches"] = {"value": n, "limit": 1,
                                            "ok": n >= 1}
-    if "resilver" in ops:
+    if ops & set(store.REPAIR_OPS):
         checks.update(_check_repair(work, cell, seed, records, names,
                                     counters))
     return checks
@@ -160,7 +162,7 @@ def compare(work: str, cell, seed: int, records: list, names: list,
 def _check_repair(work: str, cell, seed: int, records: list, names: list,
                   counters: dict) -> dict:
     traffic = cell.traffic
-    jobs = [r for r in records if r[0] == "resilver"]
+    jobs = [r for r in records if r[0] in store.REPAIR_OPS]
     bad_jobs = sum(r[3] != 200 for r in jobs)
     # redundancy: each distinct chunk file is hashed once, in threads
     # (hashlib releases the interpreter lock on large buffers)
@@ -191,7 +193,15 @@ def _check_repair(work: str, cell, seed: int, records: list, names: list,
         a, b = _check_put(work, ref_mod, cell.config, body, name)
         bad_ref += a
         bad_chunks += b
-    n = counters["reconstruct_batch_dispatches"]
+    if traffic["op"] == "scrub":
+        # plans run in place, of every code; a fallback is not one
+        via = "repair_plans"
+        n = sum(v for k, v in counters.items() if k.startswith("repair.")
+                and k.rsplit(".", 1)[1] in ("plans_copy", "plans_decode",
+                                            "plans_msr"))
+    else:
+        via = "decode_dispatches"
+        n = counters["reconstruct_batch_dispatches"]
     return {
         "repairs": {"value": len(jobs), "limit": 1, "ok": len(jobs) >= 1},
         "failed_repairs": {"value": bad_jobs, "limit": 0,
@@ -206,5 +216,5 @@ def _check_repair(work: str, cell, seed: int, records: list, names: list,
                                 "ok": bad_ref == 0},
         "chunk_mismatches": {"value": bad_chunks, "limit": 0,
                              "ok": bad_chunks == 0},
-        "decode_dispatches": {"value": n, "limit": 1, "ok": n >= 1},
+        via: {"value": n, "limit": 1, "ok": n >= 1},
     }

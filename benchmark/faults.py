@@ -2,13 +2,15 @@
 benchmark's own runs use none of them.
 
 Each is a context manager that breaks the timed path underneath the
-gateway, at the codec (``ErasureCoder``, ``ops/backend.py``) unless said
-otherwise, and keeps the store self-consistent (recorded digests are
-recomputed from the broken bytes), so that only the comparison with the
-reference can see it:
+gateway, at the codec (``ErasureCoder``, ``ops/backend.py``, and the
+``pm-msr`` coder's own decode and regeneration, ``ops/pm_msr.py``)
+unless said otherwise, and keeps the store self-consistent (recorded
+digests are recomputed from the broken bytes), so that only the
+comparison with the reference can see it:
 
 * ``altered``: one byte of every part's first output row flipped where
-  the codec produces it (a parity byte on PUT, a rebuilt byte on GET);
+  the codec produces it (a parity byte on PUT, a rebuilt byte on GET or
+  in a repair);
 * ``unchanged``: the codec leaves its output as it found it (zeros);
 * ``half_batch``: the second half of every batch, rounded up, is left
   out: its outputs stay zero;
@@ -19,7 +21,8 @@ reference can see it:
   On a GET cell the control is ``altered``: the exact-bytes guarantee.
   On a repair cell, the guarantee a repair's success states: each
   rebuilt chunk file is deleted once its write returns, before the job
-  is recorded.
+  is recorded; for a scrub, the writes of the repair planner's in-place
+  plans and of the resilver it falls back to alike.
 """
 
 from __future__ import annotations
@@ -55,10 +58,13 @@ def _rehash(parity: np.ndarray, digests: np.ndarray, d: int) -> np.ndarray:
 @contextlib.contextmanager
 def _codec(how: str):
     from chunky_bits_tpu.ops.backend import ErasureCoder
+    from chunky_bits_tpu.ops.pm_msr import PMMSRCoder
 
     enc1 = ErasureCoder.encode_hash_batch
     encn = ErasureCoder.encode_hash_batches
     dec = ErasureCoder.reconstruct_batch_picked
+    msr_dec = PMMSRCoder.reconstruct_batch_picked
+    msr_regen = PMMSRCoder.repair_batch
 
     def encode_hash_batch(self, data):
         parity, digests = enc1(self, data)
@@ -71,15 +77,27 @@ def _codec(how: str):
     def reconstruct_batch_picked(self, picked, present, wanted):
         return _break(dec(self, picked, present, wanted), how)
 
+    def msr_reconstruct_batch_picked(self, picked, present, wanted):
+        return _break(msr_dec(self, picked, present, wanted), how)
+
+    def repair_batch(self, failed, helpers, projections):
+        # the regenerated chunk [B, S] as one output row
+        out = msr_regen(self, failed, helpers, projections)
+        return _break(out[:, None, :], how)[:, 0, :]
+
     ErasureCoder.encode_hash_batch = encode_hash_batch
     ErasureCoder.encode_hash_batches = encode_hash_batches
     ErasureCoder.reconstruct_batch_picked = reconstruct_batch_picked
+    PMMSRCoder.reconstruct_batch_picked = msr_reconstruct_batch_picked
+    PMMSRCoder.repair_batch = repair_batch
     try:
         yield
     finally:
         ErasureCoder.encode_hash_batch = enc1
         ErasureCoder.encode_hash_batches = encn
         ErasureCoder.reconstruct_batch_picked = dec
+        PMMSRCoder.reconstruct_batch_picked = msr_dec
+        PMMSRCoder.repair_batch = msr_regen
 
 
 @contextlib.contextmanager
@@ -143,6 +161,31 @@ def _control_resilver():
         ClusterWriter.write_shard = orig
 
 
+@contextlib.contextmanager
+def _control_scrub():
+    import os
+
+    from chunky_bits_tpu.cluster.repair import RepairPlanner
+
+    orig = RepairPlanner._write_victims
+
+    async def _write_victims(self, chunk, payload, victims, cx, code="rs"):
+        out = await orig(self, chunk, payload, victims, cx, code)
+        for victim in victims:
+            try:
+                os.remove(victim.target)
+            except FileNotFoundError:
+                pass  # a write that failed left nothing
+        return out
+
+    RepairPlanner._write_victims = _write_victims
+    try:
+        with _control_resilver():
+            yield
+    finally:
+        RepairPlanner._write_victims = orig
+
+
 FAULTS = ("altered", "unchanged", "half_batch", "no_exchange")
 
 
@@ -151,6 +194,8 @@ def plant(name: str, traffic: dict):
     if name == "control":
         ops = traffic["op"]
         ops = {o for o, _ in ops} if isinstance(ops, list) else {ops}
+        if "scrub" in ops:
+            return _control_scrub()
         if "resilver" in ops:
             return _control_resilver()
         return _control_put() if "put" in ops else _codec("altered")

@@ -15,9 +15,10 @@ imports JAX.  The window opens after the mix's warm-up seconds and lasts
 outside it.  Then the server stops, the device's peak memory is read,
 and what the window produced is compared with ``benchmark/reference.py``
 (or the reference the configuration names).  A repair mix (``op:
-resilver``) serves nothing: its ``clients`` workers run on this
-process's event loop, each replaying one destination's loss on an
-object and then the CLI's ``resilver`` on it.
+resilver`` or ``op: scrub``) serves nothing: its ``clients`` workers run
+on this process's event loop, each replaying one destination's loss on
+an object and then the CLI's ``resilver``, or the scrubber's pass of
+``scrub --once``, on it.
 
 The last stdout line is one JSON object: ``correct``, ``attempted``,
 ``failed``, ``metrics`` (the cell's end-to-end metrics with ``--trace
@@ -31,6 +32,7 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import copy
 import itertools
 import json
 import math
@@ -50,7 +52,18 @@ import spec as specmod  # noqa: E402
 import store  # noqa: E402
 
 GIB = float(1 << 30)
-OK = {"put": (200,), "get": (200,), "head": (200,), "resilver": (200,)}
+OK = {"put": (200,), "get": (200,), "head": (200,), "resilver": (200,),
+      "scrub": (200,)}
+#: the repair planner's counters the window takes per code: key -> its
+#: ``cb_repair_*`` family (``obs/metrics.py``) and labels
+REPAIR_COUNTERS = {
+    **{f"plans_{k}": ("cb_repair_plans_total", {"kind": k})
+       for k in ("copy", "decode", "msr", "fallback")},
+    **{f"helper_bytes_{s}": ("cb_repair_helper_bytes_total",
+                             {"source": s})
+       for s in ("replica", "decode", "msr")},
+    "bytes_rebuilt": ("cb_repair_bytes_rebuilt_total", {}),
+}
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
@@ -126,17 +139,34 @@ def gib_s(records: list, op: str, t0: float, t1: float) -> float:
     return done / GIB / (t1 - t0)
 
 
-def _batch_counters(op: str) -> dict:
-    """The process registry's ``cb_batch_parts_total{op}`` and
-    ``cb_batch_dispatches_total{op}`` (``obs/metrics.py``), 0 before
-    their first event."""
+def registry_counters() -> dict:
+    """From the process registry (``obs/metrics.py``), 0 before their
+    first event: ``cb_batch_parts_total`` and
+    ``cb_batch_dispatches_total`` of ``op="reconstruct"`` (every decode
+    batcher's, the per-file ones of a repair too), and the repair
+    planner's ``cb_repair_*`` counters by code, named
+    ``repair.<code>.plans_<kind>``, ``repair.<code>.helper_bytes_<source>``
+    and ``repair.<code>.bytes_rebuilt``.  The planners' counters are
+    those of the planners alive: the caller keeps its scrub daemons
+    alive across the window."""
+    from chunky_bits_tpu.cluster.repair import CODES
     from chunky_bits_tpu.obs import metrics
 
-    out = {"cb_batch_parts_total": 0.0, "cb_batch_dispatches_total": 0.0}
-    for fam in metrics.get_registry().snapshot()["families"]:
-        if fam["name"] in out:
-            out[fam["name"]] = sum(s["value"] for s in fam["samples"]
-                                   if s["labels"].get("op") == op)
+    fams = {f["name"]: f["samples"]
+            for f in metrics.get_registry().snapshot()["families"]}
+
+    def total(name: str, **labels) -> float:
+        return float(sum(s["value"] for s in fams.get(name, [])
+                         if all(s["labels"].get(k) == v
+                                for k, v in labels.items())))
+
+    out = {"reconstruct_batch_parts":
+           total("cb_batch_parts_total", op="reconstruct"),
+           "reconstruct_batch_dispatches":
+           total("cb_batch_dispatches_total", op="reconstruct")}
+    for code in CODES:
+        for key, (fam, labels) in REPAIR_COUNTERS.items():
+            out[f"repair.{code}.{key}"] = total(fam, code=code, **labels)
     return out
 
 
@@ -192,8 +222,6 @@ class Session:
         enc = cluster._encode_batchers.get(self.loop)
         rec = cluster._reconstruct_batchers.get(self.loop)
         stats = cluster.host_pipeline().stats()
-        # every decode batcher's, the per-file ones of a repair too
-        batches = _batch_counters("reconstruct")
         return {
             "encode_dispatches": enc.dispatches if enc else 0,
             "reconstruct_dispatches": rec.dispatches if rec else 0,
@@ -202,9 +230,7 @@ class Session:
             "verify_busy_s": sum(s.busy_s for s in stats.stages
                                  if s.stage == "verify"),
             "host_threads": stats.threads,
-            "reconstruct_batch_parts": batches["cb_batch_parts_total"],
-            "reconstruct_batch_dispatches":
-                batches["cb_batch_dispatches_total"],
+            **registry_counters(),
         }
 
     async def run(self) -> dict:
@@ -228,7 +254,7 @@ class Session:
         if self.fault is not None:
             self.fault.__enter__()
         try:
-            if "resilver" in store.ops_of(traffic):
+            if store.ops_of(traffic) & set(store.REPAIR_OPS):
                 return await self._repair(cluster, names, counter)
             return await self._serve(cluster, names, counter)
         finally:
@@ -298,20 +324,45 @@ class Session:
         """The repair loop: worker ``w`` of the mix's ``clients`` takes
         the objects ``w``, ``w + clients``, ... in turn, so no object is
         in two jobs at once.  A job replays the loss on its object
-        (``store.Loss``, not timed), then runs the CLI's ``resilver`` on
-        it (timed) and is recorded as ``["resilver", start, end, status,
-        rebuilt chunk bytes, name, None]``.  Workers stop taking jobs at
-        the window's close; the jobs in flight then are drained."""
+        (``store.Loss``, not timed), then repairs it (timed) and is
+        recorded as ``[op, start, end, status, rebuilt chunk bytes,
+        name, None]``: ``resilver`` runs the CLI's ``resilver`` on it;
+        ``scrub`` runs the scrubber's pass of ``scrub --once`` (its
+        repair planner on, its byte rate the CLI's default) over a
+        namespace that holds the object alone.  Workers stop taking jobs
+        at the window's close; the jobs in flight then are drained."""
         from chunky_bits_tpu.cli.cluster_location import ClusterLocation
         from chunky_bits_tpu.cli.config import Config
+        from chunky_bits_tpu.cluster.scrub import ScrubDaemon
 
         args, traffic = self.args, self.cell.traffic
+        op = traffic["op"]
         loss = store.Loss(self.work, names, traffic["lost_destination"])
         config = Config(clusters={"bench": {"cluster": cluster}})
         clients = int(traffic["clients"])
         records: list = []
+        # a daemon's planner counts into the registry while it lives
+        daemons: list = []
         t0 = time.monotonic() + float(traffic.get("warmup_seconds", 2))
         t1 = t0 + args.seconds
+
+        async def resilver(name: str, lost: int) -> tuple[int, int]:
+            report = await ClusterLocation(
+                "cluster", cluster="bench", path=name).resilver(config)
+            return store.repair_outcome(report, lost)
+
+        async def scrub(name: str, lost: int) -> tuple[int, int]:
+            # the cluster with its metadata narrowed to the one file;
+            # destinations, tunables, health scoreboard, host pipeline
+            # and batchers are the cluster's own
+            one = copy.copy(cluster)
+            one.metadata = store.OneFile(cluster.metadata, name)
+            daemon = ScrubDaemon(one, bytes_per_sec=None)
+            daemons.append(daemon)
+            stats = await daemon.run_once()
+            return store.scrub_outcome(stats, lost, loss.nbytes[name])
+
+        job = {"resilver": resilver, "scrub": scrub}[op]
 
         async def worker(mine: list) -> None:
             for i in itertools.count():
@@ -321,18 +372,14 @@ class Session:
                 lost = await asyncio.to_thread(loss.apply, name)
                 start = time.monotonic()
                 try:
-                    report = await ClusterLocation(
-                        "cluster", cluster="bench", path=name
-                    ).resilver(config)
+                    status, nbytes = await job(name, lost)
                 # a job that raises is recorded as failed, and the
                 # others go on
                 except Exception:
                     traceback.print_exc()
                     status, nbytes = -1, 0
-                else:
-                    status, nbytes = store.repair_outcome(report, lost)
-                records.append(["resilver", start, time.monotonic(),
-                                status, nbytes, name, None])
+                records.append([op, start, time.monotonic(), status,
+                                nbytes, name, None])
 
         tasks = [asyncio.create_task(worker(names[w::clients]))
                  for w in range(clients)]
@@ -381,7 +428,8 @@ class Session:
 
         values = {"setup_s": setup_s,
                   "put_gib_s": gib_s(records, "put", t0, t1),
-                  "repair_gib_s": gib_s(records, "resilver", t0, t1),
+                  "repair_gib_s": sum(gib_s(records, op, t0, t1)
+                                      for op in store.REPAIR_OPS),
                   "put_p95_ms": p95("put"), "get_p95_ms": p95("get")}
         device = device_info(self.devices, cell.chips)
         result = {"correct": None, "attempted": len(in_window),

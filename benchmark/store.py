@@ -1,6 +1,6 @@
 """The cell's store: its cluster description, the objects a GET or a
 repair mix reads, the loss a repair mix replays, and the warm-up of
-every program the mix dispatches.
+every program the mix dispatches under the profile's erasure code.
 
 Destinations are directories under ``<checkout>/.bench_work/store`` on
 the machine's disk, one per shard slot, with a JSON metadata directory
@@ -104,15 +104,18 @@ class Loss:
         self.refs: dict[str, bytes] = {}
         #: name -> the chunk files it records under the lost directory
         self.files: dict[str, list[str]] = {}
+        #: name -> the bytes of those chunk files
+        self.nbytes: dict[str, int] = {}
         for name in names:
             with open(meta_path(work, name), "rb") as f:
                 raw = f.read()
             ref = json.loads(raw)
             self.refs[name] = raw
-            self.files[name] = sorted({
-                loc for part in ref["parts"]
-                for chunk in part["data"] + part["parity"]
-                for loc in chunk["locations"] if loc.startswith(lost)})
+            sizes = {loc: part["chunksize"] for part in ref["parts"]
+                     for chunk in part["data"] + part["parity"]
+                     for loc in chunk["locations"] if loc.startswith(lost)}
+            self.files[name] = sorted(sizes)
+            self.nbytes[name] = sum(sizes.values())
 
     def apply(self, name: str) -> int:
         """Lose ``name``'s chunks on the destination; returns how many
@@ -139,6 +142,37 @@ def repair_outcome(report, lost: int) -> tuple[int, int]:
     return (200 if ok else 500), nbytes
 
 
+def scrub_outcome(stats, lost: int, nbytes: int) -> tuple[int, int]:
+    """``(status, rebuilt chunk bytes)`` of a scrub pass over one object
+    that lost ``lost`` chunks of ``nbytes`` bytes: 200 only when the pass
+    rewrote every one of them and no repair failed."""
+    ok = stats.repaired == lost and stats.repair_failures == 0
+    return (200, nbytes) if ok else (500, 0)
+
+
+class OneFile:
+    """The store's metadata seen as a namespace that holds one file: a
+    scrub pass over a cluster with this metadata scrubs that file alone.
+    Reads and writes go to the store itself."""
+
+    def __init__(self, metadata, name: str) -> None:
+        self.metadata = metadata
+        self.name = name
+
+    async def list(self, path: str) -> list:
+        from chunky_bits_tpu.cluster.metadata import FileOrDirectory
+
+        # the listing's own top entry, then its one child
+        return [FileOrDirectory("directory", path),
+                FileOrDirectory("file", self.name)]
+
+    async def read(self, path: str):
+        return await self.metadata.read(path)
+
+    async def write(self, path: str, payload) -> None:
+        await self.metadata.write(path, payload)
+
+
 def bytes_on_disk(work: str) -> int:
     total = 0
     for dirpath, _dirs, files in os.walk(os.path.join(work, "store")):
@@ -147,18 +181,20 @@ def bytes_on_disk(work: str) -> int:
     return total
 
 
-def shard_lengths(config: dict, sizes) -> dict[int, int]:
+def shard_lengths(config: dict, sizes, shard_len) -> dict[int, int]:
     """Shard length -> the most parts of that length one object of these
-    sizes has."""
+    sizes has; ``shard_len`` is the coder's, which rounds a part's
+    length up to its shard length."""
     d = config["profile"]["data"]
     chunk = 1 << config["profile"]["chunk_size"]
     out: dict[int, int] = {}
     for size in sizes:
         if size >= d * chunk:
-            out[chunk] = max(out.get(chunk, 0), size // (d * chunk))
+            s = shard_len(d * chunk)
+            out[s] = max(out.get(s, 0), size // (d * chunk))
         tail = size % (d * chunk)
         if tail:
-            s = -(-tail // d)
+            s = shard_len(tail)
             out[s] = max(out.get(s, 0), 1)
     return out
 
@@ -168,42 +204,57 @@ def _sizes(traffic: dict, key: str) -> list[int]:
     return [s for s, _ in v] if isinstance(v, list) else [v]
 
 
+#: the repair mixes' ops
+REPAIR_OPS = ("resilver", "scrub")
+
+
 def ops_of(traffic: dict) -> set[str]:
     v = traffic["op"]
     return {o for o, _ in v} if isinstance(v, list) else {v}
 
 
 def warm(cluster, config: dict, traffic: dict) -> int:
-    """Run every codec program the mix can dispatch once: encode for PUTs,
-    the decode of the lost slots for GETs, for each shard length and each
-    batch size from 1 to the mix's ``warm_batches``, or to the parts of
-    that length all clients can have in flight if that is fewer; for a
-    repair, the decode of one lost slot for each shard length and each
-    batch size up to the parts of that length one object's repair has in
-    flight.  Returns the calls made."""
+    """Run every codec program the mix can dispatch, by the profile's
+    ``code`` (default ``rs``), once at each batch size from 1 up:
+
+    * PUT: encode, up to the mix's ``warm_batches``, or to the parts of
+      that length all clients can have in flight if that is fewer;
+    * GET: the decode of the lost slots, up to the same;
+    * ``resilver``: the decode of one lost slot, up to the parts of that
+      length one object's repair has in flight;
+    * ``scrub``: the repair planner's plan programs at batch 1: for
+      ``rs`` the decode of one whole chunk, read off ``d`` helpers; for
+      ``pm-msr`` a helper's projection and the combine of ``d'`` of
+      them.
+
+    Each at every shard length the mix's objects have.  Returns the
+    calls made."""
     import numpy as np
 
     from chunky_bits_tpu.ops.backend import get_coder
 
     prof = config["profile"]
     d, p = prof["data"], prof["parity"]
-    coder = get_coder(d, p, config["backend"])
+    code = prof.get("code", "rs")
+    coder = get_coder(d, p, config["backend"], code)
     top = int(traffic.get("warm_batches", 1))
     clients = int(traffic["clients"])
+    # (input rows, shard length, largest batch, call of [batch, rows, S])
     calls = []
     ops = ops_of(traffic)
     store_sizes = (_sizes(traffic, "store_object_bytes")
                    if "store_object_bytes" in traffic else [])
+    store_lengths = shard_lengths(config, store_sizes, coder.shard_len)
     if "put" in ops:
-        for s, n in shard_lengths(
-                config, _sizes(traffic, "object_bytes")).items():
-            calls.append((s, min(top, n * clients), coder.encode_batch))
+        for s, n in shard_lengths(config, _sizes(traffic, "object_bytes"),
+                                  coder.shard_len).items():
+            calls.append((d, s, min(top, n * clients), coder.encode_batch))
     lost = sorted(traffic.get("lost_slots", []))
     if "get" in ops and lost:
         present = [i for i in range(d + p) if i not in lost]
         wanted = [i for i in lost if i < d]
-        for s, n in shard_lengths(config, store_sizes).items():
-            calls.append((s, min(top, n * clients),
+        for s, n in store_lengths.items():
+            calls.append((d, s, min(top, n * clients),
                           lambda x: coder.reconstruct_batch_picked(
                               x, present, wanted)))
     if "resilver" in ops:
@@ -213,21 +264,41 @@ def warm(cluster, config: dict, traffic: dict) -> int:
         # parity (the shape is the same whichever).  Each file's repair
         # has its own decode batcher, so a batch holds at most the parts
         # of one object that are in flight at once.
-        for s, n in shard_lengths(config, store_sizes).items():
-            calls.append((s, min(n, RESILVER_CONCURRENCY),
+        for s, n in store_lengths.items():
+            calls.append((d, s, min(n, RESILVER_CONCURRENCY),
                           lambda x: coder.reconstruct_batch_picked(
                               x, list(range(1, d + p)), [0])))
-    # the single-chip backend takes its input from an uncommitted
-    # jax.device_put, and jit caches committed and uncommitted inputs
-    # apart: warm with uncommitted zeros made on the device (no copy in)
-    on_device = config["backend"] == "jax"
+    if "scrub" in ops:
+        # a pass repairs an object's parts one after another, each with
+        # a decode batcher of its own: every plan runs at batch 1
+        for s in store_lengths:
+            if code == "pm-msr":
+                helpers = list(range(1, coder.helpers + 1))
+                calls.append((1, s, 1,
+                              lambda x: coder.project_batch(0, x[:, 0])))
+                calls.append((coder.helpers, s // coder.alpha, 1,
+                              lambda x: coder.repair_batch(0, helpers, x)))
+            else:
+                # d helpers in, the other p rows (the lost one among
+                # them) out
+                calls.append((d, s, 1,
+                              lambda x: coder.reconstruct_batch_picked(
+                                  x, list(range(p, d + p)),
+                                  list(range(p)))))
+    # the single-chip backend takes an rs coder's input from an
+    # uncommitted jax.device_put, and jit caches committed and
+    # uncommitted inputs apart: warm with uncommitted zeros made on the
+    # device (no copy in).  A pm-msr coder reshapes its input on the
+    # host first, as its callers' arrays are, so it is warmed from the
+    # host.
+    on_device = config["backend"] == "jax" and code == "rs"
     if on_device:
         import jax.numpy as jnp
     n = 0
-    for s, most, call in calls:
-        host = None if on_device else np.zeros((most, d, s), np.uint8)
+    for rows, s, most, call in calls:
+        host = None if on_device else np.zeros((most, rows, s), np.uint8)
         for b in range(1, most + 1):
-            call(jnp.zeros((b, d, s), jnp.uint8) if on_device
+            call(jnp.zeros((b, rows, s), jnp.uint8) if on_device
                  else host[:b])
             n += 1
     return n

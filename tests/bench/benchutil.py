@@ -4,6 +4,12 @@
 ``BENCHMARK.json`` that lists the given cells, the real metric readers,
 and each cell's configuration and traffic mix cut to a size the CPU
 runs in seconds — written as new files, the way a later PR adds a cell.
+
+The sizes are data: each configuration and mix file carries its own
+under ``"tiny"``, top-level keys that replace the file's own (a mix's
+``"faults"`` there lists the planted faults its cells are rehearsed
+with; ``control.py`` names them).  ``run.py`` never reads the key, so a
+cell whose files carry it is rehearsed with no test edited.
 """
 
 from __future__ import annotations
@@ -15,30 +21,6 @@ import shutil
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 BENCH = os.path.join(REPO, "benchmark")
-
-#: per config: the profile and backend the rehearsal uses
-TINY_CONFIGS = {
-    "rs10-4-1m": {"profile": {"data": 10, "parity": 4, "chunk_size": 12}},
-    "d8p3-512k": {"profile": {"data": 8, "parity": 3, "chunk_size": 12}},
-    "d20p6-4m": {"profile": {"data": 20, "parity": 6, "chunk_size": 12}},
-}
-
-#: per mix: the keys the rehearsal changes
-TINY_TRAFFIC = {
-    "put-256m": {"object_bytes": 10 * 10 * 4096 + 5 * 4096 + 123,
-                 "clients": 3, "warmup_seconds": 0.5, "warm_batches": 2},
-    "put-4m": {"object_bytes": 8 * 4096, "clients": 4,
-               "warmup_seconds": 0.5, "warm_batches": 2},
-    "get-degraded-10m": {"store_objects": 12,
-                         "store_object_bytes": 10 * 4096, "clients": 4,
-                         "warmup_seconds": 0.5, "warm_batches": 2},
-    "put-320m": {"object_bytes": 2 * 20 * 4096, "clients": 1,
-                 "warmup_seconds": 0.5, "warm_batches": 2},
-    "resilver-1disk": {"store_objects": 6,
-                       "store_object_bytes": 3 * 10 * 4096 + 5 * 4096 + 123,
-                       "clients": 3, "warmup_seconds": 0.5,
-                       "check_objects": 2},
-}
 
 
 def _load(path: str) -> dict:
@@ -52,25 +34,56 @@ def _dump(path: str, obj: dict) -> None:
         json.dump(obj, f)
 
 
-def tiny_root(tmp: str, cells: list[str], suffix: str = "") -> str:
+def cells(source: str = REPO) -> list[str]:
+    """Every workload of ``source``'s BENCHMARK.json."""
+    return [w["name"] for w in
+            _load(os.path.join(source, "BENCHMARK.json"))["workloads"]]
+
+
+def workload(cell: str, source: str = REPO) -> dict:
+    bench = _load(os.path.join(source, "BENCHMARK.json"))
+    return next(w for w in bench["workloads"] if w["name"] == cell)
+
+
+def _traffic(cell: str, source: str) -> dict:
+    return _load(os.path.join(source, "benchmark", "traffic",
+                              f"{workload(cell, source)['traffic']}.json"))
+
+
+def rehearsal_faults(cell: str, source: str = REPO) -> list[str]:
+    """The planted faults ``cell``'s mix lists for its rehearsals."""
+    return list(_traffic(cell, source)["tiny"].get("faults", []))
+
+
+def _tiny(obj: dict) -> dict:
+    tiny = dict(obj.pop("tiny"))
+    tiny.pop("faults", None)
+    obj.update(tiny)
+    return obj
+
+
+def tiny_root(tmp: str, cells: list[str], suffix: str = "",
+              source: str = REPO) -> str:
     """A checkout at ``tmp`` whose BENCHMARK.json has tiny copies of
-    ``cells``, each named ``<cell><suffix>``."""
-    real = _load(os.path.join(REPO, "BENCHMARK.json"))
+    ``source``'s ``cells``, each named ``<cell><suffix>``, with the
+    reference modules their configurations name."""
+    real = _load(os.path.join(source, "BENCHMARK.json"))
     by_name = {w["name"]: w for w in real["workloads"]}
     bench = dict(real, configs=[], workloads=[])
-    shutil.copytree(os.path.join(BENCH, "metrics"),
+    shutil.copytree(os.path.join(source, "benchmark", "metrics"),
                     os.path.join(tmp, "benchmark", "metrics"))
     for cell in cells:
         w = dict(by_name[cell], name=cell + suffix)
         cfg = next(c for c in real["configs"] if c["name"] == w["config"])
-        conf = _load(os.path.join(REPO, cfg["file"]))
-        conf.update(TINY_CONFIGS[w["config"]])
+        conf = _tiny(_load(os.path.join(source, cfg["file"])))
+        ref = conf.get("reference", "reference")
+        if ref != "reference":
+            shutil.copy(os.path.join(source, "benchmark", f"{ref}.py"),
+                        os.path.join(tmp, "benchmark", f"{ref}.py"))
         cfg = dict(cfg, name=w["config"] + suffix,
                    file=f"benchmark/configs/{w['config']}{suffix}.json")
         _dump(os.path.join(tmp, cfg["file"]), conf)
-        traffic = _load(os.path.join(BENCH, "traffic",
-                                     f"{w['traffic']}.json"))
-        traffic.update(TINY_TRAFFIC[w["traffic"]])
+        traffic = _tiny(_traffic(cell, source))
         _dump(os.path.join(tmp, "benchmark", "traffic",
                            f"{w['traffic']}{suffix}.json"), traffic)
         w.update(config=cfg["name"], traffic=w["traffic"] + suffix)

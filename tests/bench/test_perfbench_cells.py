@@ -1,8 +1,10 @@
 """Each cell rehearsed on the CPU at a tiny size, through the real
 gateway, load generator and reference check; and the entry's refusals.
 
-The tiny cells are new files written by ``benchutil.tiny_root`` under
-new names: a cell is added by data alone."""
+The cells are every workload of BENCHMARK.json, and their tiny sizes
+are the ``"tiny"`` keys of their own files: ``benchutil.tiny_root``
+writes them as new files under new names, so a cell is added, and
+rehearsed, by data alone."""
 
 import json
 import os
@@ -11,29 +13,36 @@ import sys
 
 import pytest
 
-from benchutil import BENCH, REPO, tiny_root
+from benchutil import BENCH, REPO, cells, tiny_root, workload
 
 sys.path.insert(0, BENCH)
 
 import run  # noqa: E402
+import spec  # noqa: E402
 import trace_reduce  # noqa: E402
 
-CELLS = ["rs10-4-1m.put-256m", "d8p3-512k.put-4m",
-         "rs10-4-1m.get-degraded-10m", "d20p6-4m.put-320m",
-         "rs10-4-1m.resilver-1disk"]
+CELLS = cells()
 SEED = 2**33 + 11
 
 
 def run_tiny(tmp_path, capsys, cell, *, trace=0, fault=None, main=None,
-             extra=()):
+             extra=(), source=REPO):
     """Run ``cell``'s tiny copy on the CPU; returns the result line."""
+    from chunky_bits_tpu.obs import metrics as obs_metrics
     from chunky_bits_tpu.ops.backend import get_backend
 
-    root = tiny_root(str(tmp_path), [cell], suffix=".tiny")
+    root = tiny_root(str(tmp_path), [cell], suffix=".tiny", source=source)
+    tiny = spec.load(root, cell + ".tiny")
     mp = pytest.MonkeyPatch()
-    if cell.startswith("d20p6"):
-        # the chip run spreads over four devices; the virtual mesh has 8
-        mp.setattr(get_backend("mesh"), "n_devices", 4)
+    # a fresh metrics registry, as a run's own process has: a simulator
+    # run earlier in this process swaps registries, and the program's
+    # cached event families would still count into the one it left
+    mp.setattr(obs_metrics, "_REGISTRY", None)
+    mp.setattr(obs_metrics, "_EVENT_FAMILIES", {})
+    if tiny.config["backend"] == "mesh":
+        # the chip run spreads over the cell's chips; the virtual mesh
+        # has 8
+        mp.setattr(get_backend("mesh"), "n_devices", tiny.chips)
     try:
         argv = ["--workload", cell + ".tiny", "--seed", str(SEED),
                 "--seconds", "1", "--trace", str(trace), *extra]
@@ -47,38 +56,40 @@ def run_tiny(tmp_path, capsys, cell, *, trace=0, fault=None, main=None,
     return json.loads(capsys.readouterr().out.strip().splitlines()[-1])
 
 
-@pytest.mark.parametrize("cell", CELLS)
-def test_cell_on_cpu(tmp_path, capsys, cell):
-    out = run_tiny(tmp_path, capsys, cell)
+def _bench(source):
+    with open(os.path.join(source, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+def rehearse(tmp_path, capsys, cell, source=REPO):
+    """The cell's tiny copy is correct and reports every end-to-end
+    metric it lists."""
+    out = run_tiny(tmp_path, capsys, cell, source=source)
     assert out["correct"] is True, out["checks"]
     assert out["attempted"] > 0 and out["failed"] == 0
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    want = {m["name"] for m in bench["end_to_end"]
+    want = {m["name"] for m in _bench(source)["end_to_end"]
             if cell in m.get("workloads", [cell])}
     assert set(out["metrics"]) == want
     assert all(m["value"] > 0 for m in out["metrics"].values())
     assert out["device"]["platform"] == "cpu"
     assert list(out)[-1] == "checks"
     assert not os.path.exists(os.path.join(tmp_path, ".bench_work"))
+    return out
 
 
-@pytest.mark.parametrize("cell", CELLS)
-def test_traced_cell_reports_its_layers(tmp_path, capsys, cell,
-                                        monkeypatch):
+def rehearse_traced(tmp_path, capsys, cell, monkeypatch, source=REPO):
     """``--trace 1`` on the CPU with the reduction fed the recorded v5e
     trace (a CPU trace has no device plane): every per-layer metric the
     cell lists is in its line, with the breakdown."""
     recorded = trace_reduce.reduce(
         os.path.join(BENCH, "testdata", "v5e-codec.xplane.pb"),
         "TPU v5 lite", 1)
-    recorded.chips = recorded.chips * (4 if cell.startswith("d20") else 1)
+    recorded.chips = recorded.chips * int(workload(cell, source)["chips"])
     monkeypatch.setattr(trace_reduce, "reduce", lambda *a: recorded)
-    out = run_tiny(tmp_path, capsys, cell, trace=1)
+    out = run_tiny(tmp_path, capsys, cell, trace=1, source=source)
     assert out["correct"] is True, out["checks"]
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    want = {m["name"] for m in bench["per_layer"] if cell in m["workloads"]}
+    want = {m["name"] for m in _bench(source)["per_layer"]
+            if cell in m["workloads"]}
     assert set(out["metrics"]) == want
     for name, m in out["metrics"].items():
         assert m["value"] >= 0, name
@@ -86,6 +97,18 @@ def test_traced_cell_reports_its_layers(tmp_path, capsys, cell,
             assert 0 < m["value"] <= 100
     assert 0 < out["device"]["busy_s"] < out["device"]["window_s"]
     assert out["breakdown"]["device_ops"] and out["breakdown"]["idle_gaps"]
+    return out
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_cell_on_cpu(tmp_path, capsys, cell):
+    rehearse(tmp_path, capsys, cell)
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_traced_cell_reports_its_layers(tmp_path, capsys, cell,
+                                        monkeypatch):
+    rehearse_traced(tmp_path, capsys, cell, monkeypatch)
 
 
 def _entry(cwd, env_extra=None):

@@ -1,12 +1,13 @@
 """Each cell's control and planted faults, on the CPU at a tiny size:
 the harness's look for a chip is skipped, the rest of a run is driven,
-and ``correct`` must come out false."""
+and ``correct`` must come out false.  The faults of a cell are those
+its mix's ``"tiny"`` key lists."""
 
 import sys
 
 import pytest
 
-from benchutil import BENCH
+from benchutil import BENCH, cells, rehearsal_faults
 from test_perfbench_cells import run_tiny
 
 sys.path.insert(0, BENCH)
@@ -14,21 +15,8 @@ sys.path.insert(0, BENCH)
 import control  # noqa: E402
 
 #: per cell, the faults it can have
-CASES = [
-    (cell, fault)
-    for cell, faults in [
-        ("rs10-4-1m.put-256m", ["control", "altered", "unchanged",
-                                "half_batch"]),
-        ("d8p3-512k.put-4m", ["control", "altered", "unchanged",
-                              "half_batch"]),
-        ("rs10-4-1m.get-degraded-10m", ["control", "unchanged",
-                                        "half_batch"]),
-        ("d20p6-4m.put-320m", ["control", "altered", "no_exchange"]),
-        ("rs10-4-1m.resilver-1disk", ["control", "altered", "unchanged",
-                                      "half_batch"]),
-    ]
-    for fault in faults
-]
+CASES = [(cell, fault) for cell in cells()
+         for fault in rehearsal_faults(cell)]
 
 
 @pytest.mark.parametrize("cell,fault", CASES)
