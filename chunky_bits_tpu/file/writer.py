@@ -29,6 +29,8 @@ of the [stage_size, d, chunk] staging block (``aio.read_exact_into``,
 zero-copy for ``readinto``-capable readers), so full-length parts reach
 the encoder already in batched device layout with no intermediate bytes
 objects or restaging memcpy; only the short tail part is repacked.
+Staging blocks come from a process-wide :class:`StagingPool`, so the
+body bytes land in pages an earlier write already faulted in.
 
 Multi-core host plane: each staged sub-block's encode+hash runs through
 the shared host pipeline (parallel/host_pipeline.py) — per-stripe fused
@@ -41,6 +43,8 @@ write positionally into the staged batch's outputs.
 from __future__ import annotations
 
 import asyncio
+import functools
+import threading
 from dataclasses import dataclass, replace
 from typing import Optional
 
@@ -49,9 +53,76 @@ import numpy as np
 from chunky_bits_tpu.errors import FileWriteError
 from chunky_bits_tpu.file.file_part import FilePart
 from chunky_bits_tpu.file.file_reference import FileReference
+from chunky_bits_tpu.obs import metrics as obs_metrics
 from chunky_bits_tpu.obs import tracing as obs_tracing
 from chunky_bits_tpu.ops import get_coder
 from chunky_bits_tpu.utils import aio
+
+
+class StagingPool:
+    """The read loops' ``[stage_size, d, chunk]`` staging blocks, kept
+    for reuse by shape.
+
+    The read loop copies body bytes into its staging block on the event
+    loop's thread.  A fresh block of tens of MiB is a new mapping, so
+    each page the body lands in is first faulted in there, and unmapped
+    again when the block is freed.  A block handed back once its parts
+    are encoded and written keeps its pages resident for the next write
+    of the same geometry.
+
+    Bounded with no setting: a shape's free list never holds more blocks
+    than the most of that shape ever out at once, so the pool keeps at
+    most the staging memory the process's busiest moment already held.
+    Thread-safe: writers run on several event loops."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._free: dict[tuple, list[np.ndarray]] = {}
+        #: blocks of each shape taken and not yet released
+        self._out: dict[tuple, int] = {}
+        #: the most blocks of each shape ever out at once
+        self._peak: dict[tuple, int] = {}
+
+    def take(self, shape: tuple) -> np.ndarray:
+        """A block of ``shape``: a released one if there is one, else a
+        new ``np.empty``.  Counted in ``cb_writer_staging_blocks_total``
+        and spanned as ``writer.stage.reused`` / ``writer.stage.fresh``."""
+        with self._lock:
+            free = self._free.get(shape)
+            block = free.pop() if free else None
+            out = self._out[shape] = self._out.get(shape, 0) + 1
+            self._peak[shape] = max(self._peak.get(shape, 0), out)
+        source = "fresh" if block is None else "reused"
+        obs_metrics.record_staging_block(source)
+        with obs_tracing.span(f"writer.stage.{source}"):
+            if block is None:
+                block = np.empty(shape, dtype=np.uint8)
+        return block
+
+    def release(self, block: np.ndarray, reusable: bool) -> None:
+        """Give back a block taken here.  ``reusable`` only when nothing
+        can still read or write it; otherwise it is forgotten and the GC
+        frees it once its last user lets go."""
+        shape = block.shape
+        with self._lock:
+            self._out[shape] -= 1
+            free = self._free.setdefault(shape, [])
+            if reusable and len(free) < self._peak[shape]:
+                free.append(block)
+
+    def stats(self) -> dict[tuple, dict[str, int]]:
+        """Per shape: blocks ``free`` in the pool, ``out`` with writers,
+        and the ``peak`` out at once."""
+        with self._lock:
+            return {shape: {"free": len(self._free.get(shape, ())),
+                            "out": self._out[shape],
+                            "peak": self._peak[shape]}
+                    for shape in self._out}
+
+
+#: the process's staging pool, shared by every writer
+# lint: loop-shared-ok lock-guarded; writers on every loop share it
+_STAGING = StagingPool()
 
 
 @dataclass
@@ -199,11 +270,13 @@ class FileWriteBuilder:
             max(2 * stage_size, batch_parts if merging else 0))
         chunk = self.chunk_size
         part_bytes = d * chunk
-        # The current staging block: the read loop lands part bytes
-        # DIRECTLY into rows of this [stage_size, d, chunk] array (via
-        # readinto when the reader supports it), so a full-length part
-        # reaches the encoder with zero restaging copies — the bytes are
-        # already in batched [B, d, S] device layout.
+        # The current staging block, taken from the staging pool: the
+        # read loop lands part bytes DIRECTLY into rows of this
+        # [stage_size, d, chunk] array (via readinto when the reader
+        # supports it), so a full-length part reaches the encoder with
+        # zero restaging copies — the bytes are already in batched
+        # [B, d, S] device layout.
+        pool = _STAGING
         block: Optional[np.ndarray] = None
         lens: list[int] = []
         total_bytes = 0
@@ -313,16 +386,33 @@ class FileWriteBuilder:
             return await aio.gather_or_cancel(
                 [write_part(x) for x in pre])
 
+        def settle(blk: np.ndarray, task: asyncio.Task) -> None:
+            # a pool block goes back for reuse only once its parts are
+            # encoded and written; after a failure or a cancellation a
+            # worker thread may still read its rows (a cancelled
+            # to_thread runs on), so it is left to the GC
+            pool.release(blk, not task.cancelled()
+                         and task.exception() is None)
+
+        def launch(blk: np.ndarray, ls: list[int], pooled: bool) -> None:
+            task = asyncio.create_task(run_batch(blk, ls))
+            if pooled:
+                task.add_done_callback(functools.partial(settle, blk))
+            batch_tasks.append(task)
+
         def flush() -> None:
             """Hand the current staging block to a background
-            encode+write task — the read loop keeps streaming into a
-            fresh block while the previous one is on the device / in
+            encode+write task — the read loop keeps streaming into
+            another block while the previous one is on the device / in
             flight to storage (double buffering; the semaphore still
             bounds total parts in flight)."""
             nonlocal block, lens
             blk, ls, block, lens = block, lens, None, []
             if ls:
-                batch_tasks.append(asyncio.create_task(run_batch(blk, ls)))
+                launch(blk, ls, pooled=True)
+            elif blk is not None:
+                # the body ended before the block's first row
+                pool.release(blk, True)
 
         checked = 0
 
@@ -381,13 +471,12 @@ class FileWriteBuilder:
                             # lint: lock-discipline-ok permit transferred to the write task
                             await encode_ahead.acquire()
                         total_bytes += blk.shape[0] * part_bytes
-                        block, lens = blk, [part_bytes] * blk.shape[0]
-                        flush()
+                        launch(blk, [part_bytes] * blk.shape[0],
+                               pooled=False)
                         check_failed()
                         continue
                 if block is None:
-                    block = np.empty((stage_size, d, chunk),
-                                     dtype=np.uint8)
+                    block = pool.take((stage_size, d, chunk))
                 with obs_tracing.span("writer.receive") as receive:
                     got = await aio.read_exact_into(
                         reader, memoryview(block[len(lens)].reshape(-1)))
@@ -415,6 +504,9 @@ class FileWriteBuilder:
             # blind deletion could destroy durable data.  Orphans are
             # reclaimed by the reference-checking find-unused-hashes GC
             # (reference behavior, main.rs:329-435).
+            if block is not None:
+                # a cancelled readinto may still be filling it
+                pool.release(block, False)
             await cancel_all()
             raise
         finally:

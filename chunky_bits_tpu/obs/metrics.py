@@ -407,6 +407,17 @@ def record_chunk_publish(outcome: str) -> None:
         labels=("outcome",))).labels(outcome=outcome).inc()
 
 
+def record_staging_block(source: str) -> None:
+    """One staging block taken by a writer's read loop
+    (``file/writer.py``): ``reused`` from the staging pool, its pages
+    already resident, or ``fresh``, newly allocated."""
+    source = source if source in ("reused", "fresh") else "other"
+    _event_family("staging_blocks", lambda reg: reg.counter(
+        "cb_writer_staging_blocks_total",
+        "writer staging blocks taken by source",
+        labels=("source",))).labels(source=source).inc()
+
+
 def record_batch(op: str, parts: int = 0, dispatches: int = 0) -> None:
     """One coalesced group of a codec batcher (``ops/batching.py``): the
     parts it carried and the codec dispatches it issued, so parts per

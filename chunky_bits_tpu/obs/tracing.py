@@ -43,6 +43,8 @@ The span catalog (names are stable: benchmark readers select by name):
 ``gateway.put.admit``       PUT admission: QoS write grant, ingest slot
 ``gateway.get.send``        one body write to the client
 ``writer.receive``          the read loop waiting on body bytes (bytes)
+``writer.stage.<source>``   a staging block taken from the writer's pool:
+                            ``reused`` or ``fresh`` (allocated)
 ``writer.encode``           a staged block's parity and digests (parts,
                             bytes)
 ``meta.write``/``meta.read``  file-reference publish (with fsync) / load

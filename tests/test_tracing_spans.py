@@ -112,6 +112,18 @@ def test_each_chunk_publication_lies_inside_its_write(trace):
         assert all(w[2]["line"] != stats["line"] for w in inside)
 
 
+def test_the_traced_put_stages_in_the_warm_puts_block(trace):
+    """The untraced warm PUT of the same geometry gave its staging block
+    back, so the traced PUT's one ``writer.stage.*`` span is
+    ``writer.stage.reused``, carrying its request's ``req``."""
+    _, events = trace
+    put_req = next(r for r, (m, _, _) in _requests(events).items()
+                   if m == "gateway.put")
+    stages = [(name, stats.get("req")) for _, _, name, stats in events
+              if name.startswith("writer.stage.")]
+    assert stages == [("writer.stage.reused", put_req)]
+
+
 def test_child_spans_lie_inside_their_request(trace):
     _, events = trace
     reqs = _requests(events)
